@@ -63,7 +63,7 @@ from repro.models import init_model
 from repro.serve import ServeEngine, enable_compile_cache, synthetic_request
 
 spec = json.loads(sys.argv[1])
-enable_compile_cache(spec["cache_dir"])
+enable_compile_cache()
 cfg = get_config(spec["arch"], smoke=True)
 cfg = cfg.replace(sparsity=dataclasses.replace(
     cfg.sparsity, mode="compressed", impl="xla"))
@@ -101,6 +101,7 @@ def _bring_up(arch: str, cache_dir: str, prewarm: bool, plens, gens,
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (src, env.get("PYTHONPATH")) if p)
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     proc = subprocess.run([sys.executable, "-c", _CHILD, json.dumps(spec)],
                           capture_output=True, text=True, env=env,
                           timeout=900)

@@ -31,7 +31,7 @@ assert jnp.allclose(decompress(sp), sparsify(w, 2, 4))
 print("== 2. one matmul, four implementations ===================")
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16))
 y_ref = nm_matmul(x, sp, impl="ref")
-for impl in ("xla", "xla_gather", "pallas_interpret"):
+for impl in ("xla", "xla_gather", "pallas"):
     y = nm_matmul(x, sp, impl=impl)
     err = float(jnp.abs(y - y_ref).max())
     print(f"  {impl:18s} max|err| vs ref = {err:.2e}")

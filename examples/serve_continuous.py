@@ -27,7 +27,7 @@ def main():
                     help="comma list of gen budgets cycled over the trace")
     ap.add_argument("--arrival-every", type=int, default=0)
     ap.add_argument("--impl", default="xla",
-                    help="xla | xla_gather | pallas_interpret")
+                    help="xla | xla_gather | pallas")
     args = ap.parse_args()
 
     cfg = get_config(args.arch, smoke=True)

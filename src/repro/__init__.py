@@ -4,11 +4,3 @@ al., "Optimizing Structured-Sparse Matrix Multiplication in RISC-V Vector
 Processors", 2025)."""
 
 __version__ = "1.0.0"
-
-# jax forward-compat shims (jax.shard_map, pallas CompilerParams, ...) —
-# idempotent; also installed by src/sitecustomize.py for raw child processes
-# that touch jax before importing repro.
-from repro._compat import install as _install_jax_compat
-
-_install_jax_compat()
-del _install_jax_compat

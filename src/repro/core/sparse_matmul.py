@@ -9,8 +9,12 @@ One entry point (``nm_matmul``) with several implementations:
   xla_gather       gather-MAC formulation (Alg 6 semantics) — N/M flops; used
                    for small-batch decode on CPU where XLA executes the real
                    FLOP reduction.
-  pallas           TPU kernel (kernels/nm_spmm.py)
-  pallas_interpret TPU kernel body executed in interpret mode (CPU validation)
+  pallas           tile kernel (kernels/nm_spmm.py)
+  spmv             decode kernel (kernels/nm_spmv.py)
+  ring             TP serving: explicit sparse ring collective
+
+The Pallas impls run natively on a TPU and in interpret mode elsewhere
+(``kernels.ops.pallas_interpret``), so CPU runs validate the kernel bodies.
 
 Training uses ``nm_matmul_ste``: SR-STE (Zhou et al., paper ref [3]) —
 the N:M mask is recomputed from the dense weights every step, gradients pass
@@ -26,13 +30,11 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.sparsity import NMSparse, nm_mask
+from repro.core.sparsity import NMSparse, nm_mask, top_planes
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
-Impl = str  # 'auto' | 'ref' | 'xla' | 'xla_gather' | 'pallas' | 'pallas_interpret'
-            # | 'spmv' | 'spmv_gather' | 'spmv_onehot' | 'spmv_interpret'
-            # | 'ring' (TP serving: explicit sparse ring collective)
+Impl = str  # 'auto' | 'ref' | 'xla' | 'xla_gather' | 'pallas' | 'spmv' | 'ring'
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,12 +54,9 @@ class SparsityConfig:
     # Alg 6: weight stream read once, indirect local reads of the resident
     # activations), everything else keeps the nm_spmm tile path.  decode_impl
     # pins the decode-side choice ('auto' resolves per backend: spmv on TPU,
-    # the fused _decompress_xla formulation elsewhere); spmv_mode picks the
-    # kernel body ('gather' = true N/M-flop vindexmac, 'onehot' =
-    # decompress-in-VMEM + MXU dot fallback, guaranteed TPU lowering).
+    # the fused _decompress_xla formulation elsewhere).
     decode_impl: Impl = "auto"
     decode_batch_max: int = 8
-    spmv_mode: str = "gather"    # 'gather' | 'onehot'
     # serve-path collective experiment (§Perf falcon_gatherc/prefill
     # iterations): force the FSDP all-gather to move the COMPRESSED stream by
     # pinning the dense view to TP-only sharding.  MEASURED VERDICT: neutral
@@ -89,13 +88,13 @@ def _decompress_xla(values: jax.Array, indices: jax.Array, n: int, m: int,
     all temporaries [O, K] and elementwise -> fuses to one XLA pass."""
     o, nnz = values.shape
     nb = k // m
-    vals3 = values.reshape(o, nb, n)
-    idx3 = indices.reshape(o, nb, n).astype(jnp.int32)
+    vals3 = values.reshape(o, n, nb)
+    idx3 = indices.reshape(o, n, nb).astype(jnp.int32)
     kpos = jax.lax.broadcasted_iota(jnp.int32, (o, k), 1) % m
     dense = jnp.zeros((o, k), dtype=values.dtype)
     for s in range(n):
-        val_s = jnp.repeat(vals3[:, :, s], m, axis=1)
-        idx_s = jnp.repeat(idx3[:, :, s], m, axis=1)
+        val_s = jnp.repeat(vals3[:, s], m, axis=1)
+        idx_s = jnp.repeat(idx3[:, s], m, axis=1)
         dense = dense + jnp.where(idx_s == kpos, val_s, jnp.zeros((), values.dtype))
     return dense
 
@@ -114,7 +113,7 @@ def _xwt_xla(x, values, indices, n, m, gather_compressed=True):
 def _xwt_xla_gather(x, values, indices, n, m):
     """Gather-MAC: true N/M flops (Alg 6 executed by XLA)."""
     o, nnz = values.shape
-    blk = (jnp.arange(nnz, dtype=jnp.int32) // n) * m
+    blk = (jnp.arange(nnz, dtype=jnp.int32) % (nnz // n)) * m
     full_idx = blk[None, :] + indices.astype(jnp.int32)      # [o, nnz]
     xg = jnp.take(x, full_idx, axis=-1)                      # [..., o, nnz]
     y = jnp.einsum("...oe,oe->...o", xg.astype(jnp.float32),
@@ -134,7 +133,7 @@ def nm_rerank(values: jax.Array, indices: jax.Array, n: int, m: int,
     decoding: the same weight pool read at a cheaper fidelity through the
     same nm_spmv index stream, at keep/n the values+index bytes.
 
-    values [..., rows, nnz], indices int [..., rows, nnz] (block-major, as
+    values [..., rows, nnz], indices int [..., rows, nnz] (slot-planar, as
     produced by ``sparsity.compress``) -> the same layout with
     nnz' = nnz // n * keep."""
     if not 0 < keep < n:
@@ -143,25 +142,24 @@ def nm_rerank(values: jax.Array, indices: jax.Array, n: int, m: int,
     if nnz % n:
         raise ValueError(f"nnz {nnz} not divisible by n={n}")
     g = nnz // n
-    v = values.reshape(values.shape[:-1] + (g, n))
-    i = indices.reshape(indices.shape[:-1] + (g, n))
-    # top-|keep| per block; ties resolve to the lowest slot (deterministic)
-    _, sel = jax.lax.top_k(jnp.abs(v.astype(jnp.float32)), keep)
-    vs = jnp.take_along_axis(v, sel, axis=-1)
-    ix = jnp.take_along_axis(i, sel, axis=-1)
-    # restore ascending in-block column order (the compress invariant)
-    order = jnp.argsort(ix, axis=-1)
-    vs = jnp.take_along_axis(vs, order, axis=-1)
-    ix = jnp.take_along_axis(ix, order, axis=-1)
-    out = values.shape[:-1] + (g * keep,)
-    return vs.reshape(out), ix.reshape(out)
+    slots = lambda a: [a[..., s * g:(s + 1) * g] for s in range(n)]  # noqa: E731
+    # top-|keep| per block, ties to the lowest slot; kept slots stay in slot
+    # order, which is ascending in-block column order (the compress invariant)
+    vs, ix = top_planes(slots(values), slots(indices), keep, indices.dtype)
+    return jnp.concatenate(vs, -1), jnp.concatenate(ix, -1)
+
+
+def _kernels_apply() -> bool:
+    """The Pallas kernels serve on a TPU outside a multi-device mesh: GSPMD
+    cannot partition a Pallas call, so sharded jits take the XLA path."""
+    from repro.dist.api import current_mesh
+    mesh = current_mesh()
+    return (jax.default_backend() == "tpu"
+            and (mesh is None or mesh.size == 1))
 
 
 def default_impl(x_shape: Tuple[int, ...]) -> Impl:
-    backend = jax.default_backend()
-    if backend == "tpu":
-        return "pallas"
-    return "xla"
+    return "pallas" if _kernels_apply() else "xla"
 
 
 def is_decode_shape(x_shape: Tuple[int, ...], batch_max: int = 8) -> bool:
@@ -178,9 +176,9 @@ def select_impl(cfg: SparsityConfig, x_shape: Tuple[int, ...]) -> Impl:
 
     An explicitly pinned ``cfg.impl`` always wins.  Under 'auto', decode-
     shaped inputs route to the spmv path — the pallas vindexmac kernel on
-    TPU, the fused slot-loop decompress ('xla', bitwise-identical to the
-    kernel's decompress order) on other backends — and prefill/training
-    shapes keep the nm_spmm tile path (pallas on TPU, 'xla' elsewhere).
+    TPU, the fused slot-loop decompress ('xla') on other backends and under
+    a multi-device mesh — and prefill/training shapes keep the nm_spmm tile
+    path (pallas on TPU, 'xla' otherwise).
     """
     if cfg.impl != "auto":
         return cfg.impl
@@ -189,9 +187,7 @@ def select_impl(cfg: SparsityConfig, x_shape: Tuple[int, ...]) -> Impl:
             return "ring"
         if cfg.decode_impl != "auto":
             return cfg.decode_impl
-        if jax.default_backend() == "tpu":
-            return "spmv_onehot" if cfg.spmv_mode == "onehot" else "spmv"
-        return "xla"
+        return "spmv" if _kernels_apply() else "xla"
     return default_impl(x_shape)
 
 
@@ -236,12 +232,8 @@ def nm_matmul(x: jax.Array, sp: NMSparse, impl: Impl = "auto",
         return _xwt_xla_gather(x, sp.values, sp.indices, n, m)
     if impl == "pallas":
         return kops.nm_xwt(x, sp.values, sp.indices, n, m)
-    if impl == "pallas_interpret":
-        return kops.nm_xwt(x, sp.values, sp.indices, n, m, interpret=True)
-    if impl in ("spmv", "spmv_gather", "spmv_onehot", "spmv_interpret"):
-        return kops.nm_spmv(x, sp.values, sp.indices, n, m,
-                            mode="onehot" if impl == "spmv_onehot" else "gather",
-                            interpret=(impl == "spmv_interpret"))
+    if impl == "spmv":
+        return kops.nm_spmv(x, sp.values, sp.indices, n, m)
     raise ValueError(f"unknown impl {impl!r}")
 
 

@@ -7,6 +7,11 @@ pairs where col_idx is the *in-block* position in [0, M) — the paper's few-bit
 ``col_idx`` stream.  Full column indices are reconstructed on the fly as
 ``block_id * M + col_idx`` (paper Fig 3 / Alg 3-S line 8).
 
+Slot-planar order: with NB = in_dim // M blocks, compressed entry
+``s * NB + b`` holds slot s of block b.  Each slot is then one contiguous,
+lane-dense run, which is what lets the TPU kernels read it without
+interleaving lanes (kernels/nm_spmm.py).
+
 Layout convention: a weight W used as ``y = x @ W.T`` has shape [out, in] and
 is sparsified along ``in`` (the contraction axis) — W plays the role of the
 paper's sparse matrix A, x.T the dense matrix B.
@@ -40,10 +45,10 @@ __all__ = [
 class NMSparse:
     """Compressed N:M sparse tensor (sparse along the last dense axis).
 
-    values:  [..., rows, nnz] with nnz = in_dim // m * n   (block-major order:
-             slot j belongs to block j // n, in-block slot j % n)
+    values:  [..., rows, nnz] with nnz = in_dim // m * n   (slot-planar
+             order: entry j is slot j // NB of block j % NB, NB = in_dim // m)
     indices: int8 [..., rows, nnz], each in [0, m) — in-block column index,
-             strictly increasing within a block's n slots.
+             strictly increasing over a block's n slots.
     """
 
     values: jax.Array
@@ -109,25 +114,51 @@ def sparsify(w: jax.Array, n: int, m: int) -> jax.Array:
     return w * nm_mask(w, n, m).astype(w.dtype)
 
 
+def top_planes(planes, keys, keep: int, key_dtype):
+    """Elementwise over equal-shape ``planes``: the ``keep`` of largest
+    magnitude (ties to the lower plane), in plane order, with their ``keys``
+    (arrays or scalars).  Returns ``keep`` value planes and ``keep`` key
+    planes of ``key_dtype``.
+
+    Only elementwise ops on the planes: no sort, no gather, and no minor
+    axis of length M, which a TPU pads to a whole 128-lane row (32x the
+    operand at M = 4)."""
+    mags = [jnp.abs(p).astype(jnp.float32) for p in planes]
+    shape = planes[0].shape
+    vals = [jnp.zeros(shape, planes[0].dtype) for _ in range(keep)]
+    ids = [jnp.zeros(shape, key_dtype) for _ in range(keep)]
+    slot = jnp.zeros(shape, jnp.int32)      # kept planes so far
+    for i, (p, key) in enumerate(zip(planes, keys)):
+        rank = sum(((mags[j] >= mags[i]) if j < i else (mags[j] > mags[i])
+                    ).astype(jnp.int32) for j in range(len(planes)) if j != i)
+        kept = rank < keep
+        for s in range(keep):
+            hit = kept & (slot == s)
+            vals[s] = jnp.where(hit, p, vals[s])
+            ids[s] = jnp.where(hit, key, ids[s]).astype(key_dtype)
+        slot = slot + kept.astype(jnp.int32)
+    return vals, ids
+
+
+@partial(jax.jit, static_argnames=("n", "m"))
+def _compress_planar(w: jax.Array, n: int, m: int):
+    # position planes: plane c holds in-block column c of every block
+    vals, ids = top_planes([w[..., c::m] for c in range(m)], range(m), n,
+                           jnp.int8)
+    return jnp.concatenate(vals, -1), jnp.concatenate(ids, -1)
+
+
 def compress(w: jax.Array, n: int, m: int) -> NMSparse:
     """Dense [..., in] -> compressed (top-N magnitude per block, index-sorted).
 
     The kept entries within each block are ordered by ascending in-block
     column index, matching the paper's memory layout where col_idx words are
-    streamed in order (Alg 3-S).
+    streamed in order (Alg 3-S), and stored slot-planar.
     """
     _check_nm(w.shape[-1], n, m)
-    blocks = w.reshape(*w.shape[:-1], w.shape[-1] // m, m)
-    mag = jnp.abs(blocks).astype(jnp.float32)
-    _, idx = jax.lax.top_k(mag, n)                     # [..., nb, n] unsorted
-    idx = jnp.sort(idx, axis=-1)                       # ascending in-block index
-    vals = jnp.take_along_axis(blocks, idx, axis=-1)   # [..., nb, n]
-    nnz = w.shape[-1] // m * n
-    return NMSparse(
-        values=vals.reshape(*w.shape[:-1], nnz),
-        indices=idx.astype(jnp.int8).reshape(*w.shape[:-1], nnz),
-        n=n, m=m, dense_shape=tuple(w.shape),
-    )
+    values, indices = _compress_planar(w, n, m)
+    return NMSparse(values=values, indices=indices, n=n, m=m,
+                    dense_shape=tuple(w.shape))
 
 
 def decompress(sp: NMSparse) -> jax.Array:
@@ -135,10 +166,10 @@ def decompress(sp: NMSparse) -> jax.Array:
     equivalent of the paper's ``block_id*M + col_idx`` reconstruction."""
     lead = sp.dense_shape[:-1]
     nb, n, m = sp.num_blocks, sp.n, sp.m
-    vals = sp.values.reshape(*lead, nb, n)
-    idx = sp.indices.reshape(*lead, nb, n).astype(jnp.int32)
-    onehot = jax.nn.one_hot(idx, m, dtype=sp.values.dtype)      # [..., nb, n, m]
-    dense = jnp.einsum("...bn,...bnm->...bm", vals, onehot)
+    vals = sp.values.reshape(*lead, n, nb)
+    idx = sp.indices.reshape(*lead, n, nb).astype(jnp.int32)
+    onehot = jax.nn.one_hot(idx, m, dtype=sp.values.dtype)      # [..., n, nb, m]
+    dense = jnp.einsum("...nb,...nbm->...bm", vals, onehot)
     return dense.reshape(sp.dense_shape)
 
 
@@ -201,9 +232,9 @@ def validate_nm(w_or_sp, n: int | None = None, m: int | None = None) -> bool:
     if isinstance(w_or_sp, NMSparse):
         sp = w_or_sp
         lead = sp.dense_shape[:-1]
-        idx = np.asarray(sp.indices).reshape(*lead, sp.num_blocks, sp.n)
+        idx = np.asarray(sp.indices).reshape(*lead, sp.n, sp.num_blocks)
         in_range = bool(((idx >= 0) & (idx < sp.m)).all())
-        increasing = bool((np.diff(idx, axis=-1) > 0).all()) if sp.n > 1 else True
+        increasing = bool((np.diff(idx, axis=-2) > 0).all()) if sp.n > 1 else True
         return in_range and increasing
     w, = (np.asarray(w_or_sp),)
     assert n is not None and m is not None

@@ -139,17 +139,6 @@ def ring_matmul_bytes(o: int, k: int, ndev: int, n: int = 2, m: int = 4, *,
     return ndev * (ndev - 1) * per_step
 
 
-def _shard_map_norep(fn, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across the
-    check_rep -> check_vma rename (jax 0.4.x -> 0.5+)."""
-    import inspect
-    from jax.experimental.shard_map import shard_map
-    params = inspect.signature(shard_map).parameters
-    kw = "check_rep" if "check_rep" in params else "check_vma"
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **{kw: False})
-
-
 def ring_sparse_linear(x: jax.Array, values: jax.Array, indices: jax.Array,
                        n: int, m: int, mesh, axis: str = "model"
                        ) -> jax.Array:
@@ -166,8 +155,9 @@ def ring_sparse_linear(x: jax.Array, values: jax.Array, indices: jax.Array,
     o = values.shape[-2]
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    f = _shard_map_norep(
+    f = jax.shard_map(
         lambda v, i, xl: collective_matmul_ag_sparse(v, i, xl, axis, n, m),
-        mesh=mesh, in_specs=(P(axis), P(axis), P()), out_specs=P())
+        mesh=mesh, in_specs=(P(axis), P(axis), P()), out_specs=P(),
+        check_vma=False)
     y = f(values, indices, x2)
     return y.reshape(*lead, o).astype(x.dtype)

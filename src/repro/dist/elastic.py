@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import List, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def _best_model_axis(ndev: int, prefer_model: int) -> int:
@@ -45,7 +46,10 @@ def degraded_meshes(ndev: int, losses: Sequence[int], prefer_model: int = 1
 
 
 def choose_mesh(ndev: int | None = None, prefer_model: int = 1):
-    """(data, model) Mesh over the devices currently visible to jax."""
+    """(data, model) Mesh over the devices currently visible to jax.  Auto
+    axis types: the model code places its arrays with GSPMD sharding
+    constraints (``dist.api.constrain``), which Explicit axes refuse."""
     n = ndev if ndev is not None else jax.device_count()
     m = _best_model_axis(n, prefer_model)
-    return jax.make_mesh((n // m, m), ("data", "model"))
+    return jax.make_mesh((n // m, m), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

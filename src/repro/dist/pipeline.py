@@ -54,7 +54,5 @@ def pipeline_apply(stage_fn: Callable, params, x: jax.Array, mesh,
         return lax.psum(jnp.where(s == n - 1, outs, jnp.zeros_like(outs)),
                         axis)
 
-    # jax.shard_map: present natively on current jax, installed by
-    # repro._compat on 0.4.x (importing repro guarantees it)
     return jax.shard_map(shard, mesh=mesh, in_specs=(P(axis), P()),
                          out_specs=P())(params, x)

@@ -44,6 +44,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_FA = (512, 1024)   # (bq, bk)
 _NEG = -1e30
+# the paged decode kernels compute in f32 on the MXU at full f32 precision,
+# as the XLA reference path does under "highest" matmul precision
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _fa_body(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -159,7 +162,7 @@ def _paged_gqa_body(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                     scale: float, window: Optional[int],
                     cap: Optional[float]):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -167,11 +170,12 @@ def _paged_gqa_body(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)               # [G, d]
-    k = k_ref[0, :, 0].astype(jnp.float32)            # [bs, d]
-    v = v_ref[0, :, 0].astype(jnp.float32)            # [bs, dv]
+    q = q_ref[0].astype(jnp.float32)                  # [KVH*G, KVH*d]
+    k = k_ref[0].astype(jnp.float32)                  # [bs, KVH*d]
+    v = v_ref[0].astype(jnp.float32)                  # [bs, KVH*dv]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+                            preferred_element_type=jnp.float32,
+                            precision=_F32) * scale
     if cap is not None:
         s = cap * jnp.tanh(s / cap)
 
@@ -188,12 +192,13 @@ def _paged_gqa_body(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
     acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=_F32)
     m_ref[...] = m_new
 
     @pl.when(j == t_steps - 1)
     def _store():
-        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
+        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
 
 
 def paged_gqa_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -207,44 +212,57 @@ def paged_gqa_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     [B, T], kv_len int32 [B] (valid positions per slot, current token
     included) -> [B, KVH, G, dv] float32.
 
-    Grid (B, KVH, T): kv blocks are the innermost sequential axis; block j of
+    Grid (B, T): kv blocks are the innermost sequential axis; block j of
     slot b is fetched from physical block ``block_table[b, j]`` via the
     scalar-prefetched index map, so only pool blocks a slot's table names are
     ever read (trash-block tiles beyond ``kv_len`` are fetched but fully
-    masked)."""
+    masked).  One tile holds the block's tokens for every kv head — the pool
+    viewed as [n_blocks, bs, KVH*d], a free reshape — which keeps the tile's
+    last two dims whole, as Mosaic requires.  The heads are scored in one
+    MXU pass with a block-diagonal query: row (h, g) carries q[h, g] in the
+    lanes of kv head h and zeros elsewhere, so its scores and its slice of
+    the context see only head h.  The context rows come back [KVH*G,
+    KVH*dv], and the wrapper keeps each row's own head's lanes."""
     b, kvh, g, d = q.shape
     nb, bs = k_pool.shape[:2]
     dv = v_pool.shape[-1]
     t = block_table.shape[1]
     scale = scale if scale is not None else d ** -0.5
+    rows = kvh * g
+    eye = jnp.eye(kvh, dtype=q.dtype)
+    q_bd = (q[:, :, :, None, :] * eye[None, :, None, :, None]).reshape(
+        b, rows, kvh * d)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, t),
+        grid=(b, t),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda b, h, j, tbl, lens: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, dv),
-                         lambda b, h, j, tbl, lens: (tbl[b, j], 0, h, 0)),
+            pl.BlockSpec((1, rows, kvh * d), lambda b, j, tbl, lens: (b, 0, 0)),
+            pl.BlockSpec((1, bs, kvh * d),
+                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, bs, kvh * dv),
+                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dv),
-                               lambda b, h, j, tbl, lens: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, rows, kvh * dv),
+                               lambda b, j, tbl, lens: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
+            pltpu.VMEM((rows,), jnp.float32),
+            pltpu.VMEM((rows,), jnp.float32),
+            pltpu.VMEM((rows, kvh * dv), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    o = pl.pallas_call(
         functools.partial(_paged_gqa_body, bs=bs, t_steps=t, scale=scale,
                           window=window, cap=cap),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, rows, kvh * dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_table, kv_len, q, k_pool, v_pool)
+    )(block_table, kv_len, q_bd, k_pool.reshape(nb, bs, kvh * d),
+      v_pool.reshape(nb, bs, kvh * dv))
+    o = jnp.diagonal(o.reshape(b, kvh, g, kvh, dv), axis1=1, axis2=3)
+    return jnp.moveaxis(o, -1, 1)                     # [B, KVH, G, dv]
 
 
 def _paged_mla_body(tbl_ref, len_ref, ql_ref, qp_ref, c_ref, p_ref, o_ref,
@@ -265,9 +283,11 @@ def _paged_mla_body(tbl_ref, len_ref, ql_ref, qp_ref, c_ref, p_ref, o_ref,
     kpe = p_ref[0].astype(jnp.float32)                # [bs, rd]
     # absorbed scores: latent + rope contributions, both against the pool
     s = (jax.lax.dot_general(ql, ckv, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+                             preferred_element_type=jnp.float32,
+                             precision=_F32)
          + jax.lax.dot_general(qp, kpe, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)) * scale
+                               preferred_element_type=jnp.float32,
+                               precision=_F32)) * scale
 
     kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     mask = kpos < len_ref[b]
@@ -280,7 +300,8 @@ def _paged_mla_body(tbl_ref, len_ref, ql_ref, qp_ref, c_ref, p_ref, o_ref,
     l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
     # the value stream IS the latent cache (MLA's absorbed formulation)
     acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-        p, ckv, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, ckv, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=_F32)
     m_ref[...] = m_new
 
     @pl.when(j == t_steps - 1)

@@ -1,104 +1,38 @@
 """Pallas TPU kernel: N:M sparse matrix x small dense batch (decode regime).
 
-This is the faithful transplant of the paper's Algorithm 6: the activation
-matrix x (the "tile of B") is resident in VMEM across the whole row sweep, and
-every access the sparse format implies is an *indirect local read* — the
-vindexmac dataflow.  Because decode is memory-bound on the weight stream, the
-kernel's win is the compressed A traffic (values N/M of dense + 2-bit
-indices); the gather mode additionally performs only the N/M non-zero MACs
-(the VPU analogue of the instruction's multiply-accumulate).
+The paper's Algorithm 6 on the TPU: the activation matrix x (the "tile of
+B") is resident in VMEM across the whole row sweep, and every access the
+sparse format implies is an *indirect local read* — the vindexmac dataflow.
+Decode is memory-bound on the weight stream, so the kernel's win is the
+compressed A traffic (values N/M of dense + 2-bit indices).
 
-Modes:
-  gather : per-slot take_along_axis into the VMEM-resident x blocks —
-           literal vindexmac semantics; N/M of dense FLOPs.
-  onehot : decompress-in-VMEM + MXU dot (same as nm_spmm) — guaranteed TPU
-           lowering; same HBM bytes, dense FLOPs on a tiny batch.
+The body is ``nm_xwt``'s (decompress into position planes in VMEM, then MXU
+dots): the batch is one row tile, and the weight tiles are wider, because a
+decode step streams every weight exactly once.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.nm_spmm import _decompress_tile
+from repro.kernels.nm_spmm import nm_xwt_kernel
 
 DEFAULT_BLOCK_SPMV = (128, 1024)  # (bo, bk)
-
-
-def _spmv_body(x_ref, vals_ref, idx_ref, out_ref, acc_ref, *,
-               n: int, m: int, bk: int, k_steps: int, mode: str, out_dtype):
-    @pl.when(pl.program_id(1) == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...].astype(jnp.float32)              # [B, bk] resident tile
-    bo = vals_ref.shape[0]
-    nb = bk // m
-
-    if mode == "onehot":
-        w_tile = _decompress_tile(vals_ref[...], idx_ref[...], n, m, bk)
-        acc_ref[...] += jax.lax.dot_general(
-            x, w_tile, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    else:  # gather — vindexmac-faithful indirect reads of the resident tile
-        xb = x.reshape(x.shape[0], nb, m)
-        vals3 = vals_ref[...].reshape(bo, nb, n)
-        idx3 = idx_ref[...].reshape(bo, nb, n).astype(jnp.int32)
-        acc = jnp.zeros_like(acc_ref)
-        for s in range(n):  # static, n <= 4
-            idx_s = idx3[:, :, s]                                    # [bo, nb]
-            vals_s = vals3[:, :, s].astype(jnp.float32)              # [bo, nb]
-            g = jnp.take_along_axis(xb[:, None, :, :],
-                                    idx_s[None, :, :, None],
-                                    axis=3)[..., 0]                  # [B, bo, nb]
-            acc = acc + jnp.sum(g * vals_s[None], axis=-1)           # [B, bo]
-        acc_ref[...] += acc
-
-    @pl.when(pl.program_id(1) == k_steps - 1)
-    def _store():
-        out_ref[...] = acc_ref[...].astype(out_dtype)
 
 
 def nm_spmv_kernel(x: jax.Array, values: jax.Array, indices: jax.Array,
                    n: int, m: int, *,
                    block: Tuple[int, int] = DEFAULT_BLOCK_SPMV,
-                   mode: str = "gather", out_dtype=None,
-                   interpret: bool = False) -> jax.Array:
-    """Y = X @ W_sp.T with X a small batch [B, K]; W compressed [O, K//M*N].
+                   out_dtype=None, interpret: bool = False) -> jax.Array:
+    """Y = X @ W_sp.T with X a small batch [B, K]; W compressed slot-planar
+    [O, K//M*N].
 
     All dims pre-padded to block multiples by ops.py.  The batch is not tiled
-    (decode batches are small); the grid is (O tiles, K steps) and x's
+    (decode batches are small); the grid is (1, O tiles, K steps) and x's
     BlockSpec keeps the current K-slice resident across the O sweep.
     """
     bo, bk = block
-    if bk % m:
-        raise ValueError(f"bk={bk} must be a multiple of M={m}")
-    bsz, k = x.shape
-    o, nnz = values.shape
-    assert nnz == k // m * n, (x.shape, values.shape, n, m)
-    bnnz = bk // m * n
-    k_steps = k // bk
-    out_dtype = out_dtype or x.dtype
-    grid = (o // bo, k_steps)
-
-    return pl.pallas_call(
-        functools.partial(_spmv_body, n=n, m=m, bk=bk, k_steps=k_steps,
-                          mode=mode, out_dtype=out_dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bsz, bk), lambda j, kk: (0, kk)),
-            pl.BlockSpec((bo, bnnz), lambda j, kk: (j, kk)),
-            pl.BlockSpec((bo, bnnz), lambda j, kk: (j, kk)),
-        ],
-        out_specs=pl.BlockSpec((bsz, bo), lambda j, kk: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((bsz, o), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bsz, bo), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(x, values, indices)
+    return nm_xwt_kernel(x, values, indices, n, m, block=(x.shape[0], bo, bk),
+                         out_dtype=out_dtype, interpret=interpret)

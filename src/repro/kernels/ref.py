@@ -13,14 +13,15 @@ import jax.numpy as jnp
 
 def nm_decompress_ref(values: jax.Array, indices: jax.Array, n: int, m: int,
                       k: int) -> jax.Array:
-    """[rows, nnz] values + int8 in-block indices -> dense [rows, k]."""
+    """[rows, nnz] slot-planar values + int8 in-block indices -> dense
+    [rows, k]."""
     rows, nnz = values.shape
     assert nnz == k // m * n, (values.shape, n, m, k)
     nb = k // m
-    vals = values.reshape(rows, nb, n)
-    idx = indices.reshape(rows, nb, n).astype(jnp.int32)
-    onehot = jax.nn.one_hot(idx, m, dtype=values.dtype)       # [rows, nb, n, m]
-    dense = jnp.einsum("rbn,rbnm->rbm", vals, onehot)
+    vals = values.reshape(rows, n, nb)
+    idx = indices.reshape(rows, n, nb).astype(jnp.int32)
+    onehot = jax.nn.one_hot(idx, m, dtype=values.dtype)       # [rows, n, nb, m]
+    dense = jnp.einsum("rnb,rnbm->rbm", vals, onehot)
     return dense.reshape(rows, k)
 
 
@@ -47,7 +48,7 @@ def nm_spmv_ref(x: jax.Array, values: jax.Array, indices: jax.Array,
     x[b, block(e)*M + idx[o, e]] — an explicit gather-MAC, numerically equal
     to nm_xwt_ref but expressed the way Algorithm 6 executes it."""
     o, nnz = values.shape
-    blk = (jnp.arange(nnz, dtype=jnp.int32) // n) * m        # block base per slot
+    blk = (jnp.arange(nnz, dtype=jnp.int32) % (nnz // n)) * m  # block base
     full_idx = blk[None, :] + indices.astype(jnp.int32)      # [o, nnz]
     gathered = x.astype(jnp.float32)[:, full_idx]            # [b, o, nnz]
     y = jnp.einsum("boe,oe->bo", gathered, values.astype(jnp.float32))

@@ -37,10 +37,10 @@ launching — jax fixes its device list at backend init), and multi-process
 via ``--distributed`` (``jax.distributed.initialize``; pass
 ``--coordinator host:port --num-processes P --process-id I`` explicitly or
 let jax pick them up from the cluster environment).
-``--compile-cache [DIR]`` wires jax's persistent compilation cache to a
-repo-local directory (default ``.cache/xla``, or ``$REPRO_COMPILE_CACHE``)
-so every executable this process builds is reused by the next one — a warm
-relaunch of the same config skips XLA compilation entirely.  ``--prewarm``
+jax's persistent compilation cache is on (``--no-compile-cache`` turns it
+off): in ``$JAX_COMPILATION_CACHE_DIR`` where that is set, else in the
+repo's ``.cache/xla``, so every executable this process builds is reused by
+the next one — a warm relaunch of the same config skips XLA compilation.  ``--prewarm``
 (continuous only) AOT-compiles the engine's complete executable set —
 decode, every prefill bucket, propose/verify under ``--spec`` — at init,
 before any request is admitted, so no serving tick ever traces; the
@@ -201,13 +201,12 @@ def main() -> None:
                          "'ring' streams the compressed N:M shards through "
                          "collective_matmul_ag_sparse, 'gspmd' leaves layout "
                          "to the partitioner, 'auto' = ring when compressed")
-    ap.add_argument("--compile-cache", nargs="?", const="auto", default=None,
-                    metavar="DIR",
-                    help="persist compiled executables across process "
-                         "restarts via jax's compilation cache.  Optional "
-                         "DIR; bare flag resolves $REPRO_COMPILE_CACHE and "
-                         "then .cache/xla (the directory CI persists with "
-                         "actions/cache)")
+    ap.add_argument("--no-compile-cache", dest="compile_cache",
+                    action="store_false",
+                    help="do not persist compiled executables (by default "
+                         "jax's compilation cache keeps them in "
+                         "$JAX_COMPILATION_CACHE_DIR, else the repo's "
+                         ".cache/xla)")
     ap.add_argument("--prewarm", action="store_true",
                     help="AOT-compile the engine's complete executable set "
                          "(decode, every prefill bucket, propose/verify "
@@ -247,12 +246,11 @@ def main() -> None:
     if args.prewarm and args.scheduler != "continuous":
         raise SystemExit("--prewarm requires --scheduler continuous (the "
                          "sequential oracle has no enumerable shape set)")
-    if args.compile_cache is not None:
+    if args.compile_cache:
         # before any jit runs (init_model, conversion) so even the one-shot
         # init executables land in the persistent cache
         from repro.serve import enable_compile_cache
-        cache_dir = enable_compile_cache(args.compile_cache)
-        print(f"compile cache: {cache_dir}")
+        print(f"compile cache: {enable_compile_cache()}")
     if args.distributed:
         # must run before any jax.devices()/computation: the coordinator
         # handshake fixes the global device list

@@ -18,9 +18,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sparse_matmul import dense_forward_view, _decompress_xla
-from repro.dist.api import constrain
+from repro.dist.api import (constrain, current_mesh, current_rules,
+                            logical_to_pspec)
 from repro.kernels.flash_attention import (paged_gqa_decode, paged_gqa_verify,
                                            paged_mla_decode, paged_mla_verify)
+from repro.kernels.ops import pallas_interpret
 from repro.models.common import (Params, apply_rope, rope_angles, softcap,
                                  sp_linear_apply, sp_linear_init)
 from repro.models.config import ArchConfig
@@ -28,10 +30,29 @@ from repro.models.config import ArchConfig
 _NEG = -1e30
 
 
-def _pallas_interpret() -> bool:
-    """Fused decode kernels run natively on TPU, interpreted elsewhere (the
-    CPU serve/test path).  Resolved at trace time, inside jit."""
-    return jax.default_backend() != "tpu"
+def _fused_kernel(kernel, args, head_axes, n_heads: int, **kw):
+    """Call a fused paged-attention kernel, natively on a TPU and interpreted
+    elsewhere.  GSPMD cannot partition a Pallas call, so under a
+    multi-device mesh the call is a ``shard_map`` over the heads: operand i
+    splits on axis ``head_axes[i]`` (None: replicated, like the block table),
+    the output on the query's head axis.  Heads that do not divide the mesh
+    replicate, as the pool's own head axis does then."""
+    fn = functools.partial(kernel, interpret=pallas_interpret(), **kw)
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    from jax.sharding import PartitionSpec as P
+    heads = logical_to_pspec(("act_heads",), current_rules(), mesh=mesh,
+                             shape=(n_heads,))
+    name = heads[0] if len(heads) else None
+
+    def spec(axis):
+        if axis is None or name is None:
+            return P()
+        return P(*([None] * axis + [name]))
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=tuple(spec(a) for a in head_axes),
+                         out_specs=spec(head_axes[0]), check_vma=False)(*args)
 
 
 def _pick_chunk(s: int, want: int) -> int:
@@ -275,11 +296,12 @@ def gqa_apply(p: Params, x: jax.Array, cfg: ArchConfig, *,
             # bandwidth win the gather path below throws away)
             new_kv = _paged_write(cache, {"k": k[:, 0], "v": v[:, 0]},
                                   block_table, cache_pos)
-            o = paged_gqa_decode(q.reshape(b, kv, h // kv, hd),
-                                 new_kv["k"], new_kv["v"], block_table,
-                                 _paged_kv_len(cache_pos), scale=hd ** -0.5,
-                                 window=window, cap=cfg.softcap_attn,
-                                 interpret=_pallas_interpret())
+            o = _fused_kernel(
+                paged_gqa_decode,
+                (q.reshape(b, kv, h // kv, hd), new_kv["k"], new_kv["v"],
+                 block_table, _paged_kv_len(cache_pos)),
+                (1, 2, 2, None, None), kv, scale=hd ** -0.5, window=window,
+                cap=cfg.softcap_attn)
             o = o.reshape(b, 1, h, hd).astype(x.dtype)
         else:
             # fused paged verify span: write all S positions, then score
@@ -287,11 +309,12 @@ def gqa_apply(p: Params, x: jax.Array, cfg: ArchConfig, *,
             # span) via one single-query kernel launch per offset
             new_kv = _paged_write_span(cache, {"k": k, "v": v},
                                        block_table, cache_pos)
-            o = paged_gqa_verify(q.reshape(b, s, kv, h // kv, hd),
-                                 new_kv["k"], new_kv["v"], block_table,
-                                 _paged_kv_len(cache_pos), scale=hd ** -0.5,
-                                 window=window, cap=cfg.softcap_attn,
-                                 interpret=_pallas_interpret())
+            o = _fused_kernel(
+                paged_gqa_verify,
+                (q.reshape(b, s, kv, h // kv, hd), new_kv["k"], new_kv["v"],
+                 block_table, _paged_kv_len(cache_pos)),
+                (2, 2, 2, None, None), kv, scale=hd ** -0.5, window=window,
+                cap=cfg.softcap_attn)
             o = o.reshape(b, s, h, hd).astype(x.dtype)
     elif block_table is not None and s > 1:
         # paged verify span, gather read: write the span, gather the table
@@ -490,11 +513,11 @@ def mla_apply(p: Params, x: jax.Array, cfg: ArchConfig, *,
             qlat = jnp.einsum("bhd,hdr->bhr", qn[:, 0].astype(jnp.float32),
                               wuk3.astype(jnp.float32))
             if fused:
-                ov = paged_mla_decode(qlat, qpe[:, 0].astype(jnp.float32),
-                                      new_kv["ckv"], new_kv["kpe"],
-                                      block_table, _paged_kv_len(cache_pos),
-                                      scale=scale,
-                                      interpret=_pallas_interpret())
+                ov = _fused_kernel(
+                    paged_mla_decode,
+                    (qlat, qpe[:, 0].astype(jnp.float32), new_kv["ckv"],
+                     new_kv["kpe"], block_table, _paged_kv_len(cache_pos)),
+                    (1, 1, None, None, None, None), h, scale=scale)
             else:
                 sc = jnp.einsum("bhr,blr->bhl", qlat,
                                 cc_read.astype(jnp.float32))
@@ -515,11 +538,11 @@ def mla_apply(p: Params, x: jax.Array, cfg: ArchConfig, *,
             qlat = jnp.einsum("bshd,hdr->bshr", qn.astype(jnp.float32),
                               wuk3.astype(jnp.float32))
             if fused:
-                ov = paged_mla_verify(qlat, qpe.astype(jnp.float32),
-                                      new_kv["ckv"], new_kv["kpe"],
-                                      block_table, _paged_kv_len(cache_pos),
-                                      scale=scale,
-                                      interpret=_pallas_interpret())
+                ov = _fused_kernel(
+                    paged_mla_verify,
+                    (qlat, qpe.astype(jnp.float32), new_kv["ckv"],
+                     new_kv["kpe"], block_table, _paged_kv_len(cache_pos)),
+                    (2, 2, None, None, None, None), h, scale=scale)
             else:
                 sc = jnp.einsum("bshr,blr->bshl", qlat,
                                 cc_read.astype(jnp.float32))

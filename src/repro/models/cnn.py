@@ -15,7 +15,6 @@ import jax.numpy as jnp
 
 from repro.core.sparsity import NMSparse, compress
 from repro.core.sparse_matmul import nm_matmul
-from repro.kernels import ops as kops
 
 
 def im2col(x: jax.Array, kh: int, kw: int, stride: int = 1,
@@ -43,11 +42,7 @@ def conv2d_sparse(x: jax.Array, w_sp: NMSparse, kh: int, kw: int,
     ckp = w_sp.dense_shape[-1]
     if cols.shape[-1] < ckp:
         cols = jnp.pad(cols, ((0, 0), (0, ckp - cols.shape[-1])))
-    if impl.startswith("pallas"):
-        y = kops.nm_xwt(cols, w_sp.values, w_sp.indices, w_sp.n, w_sp.m,
-                        interpret=impl == "pallas_interpret")
-    else:
-        y = nm_matmul(cols, w_sp, impl=impl)              # [B*Ho*Wo, C_out]
+    y = nm_matmul(cols, w_sp, impl=impl)                  # [B*Ho*Wo, C_out]
     return y.reshape(b, ho, wo, -1)
 
 

@@ -81,9 +81,9 @@ always contains ``max_len``, so it is closed over every admissible
 prompt), and ``prewarm()`` AOT-compiles all of them at init, before any
 request is admitted, registering the compiled executables for direct
 dispatch (``serve.prewarm.JitEntry``) — steady-state ticks never trace.
-``compile_cache=`` additionally persists every executable across process
-restarts through jax's compilation cache (``enable_compile_cache``), so a
-warm bring-up pays lowering but not XLA compilation.  Every compile the
+``compile_cache=True`` additionally persists every executable across
+process restarts through jax's compilation cache (``enable_compile_cache``),
+so a warm bring-up pays lowering but not XLA compilation.  Every compile the
 engine does pay is accounted in ``stats()`` (per-entry counters,
 ``mid_serve_compiles``, ``compile_seconds``, first-vs-steady tick wall
 time); ``strict_prewarm=True`` turns any mid-serve compile into a hard
@@ -155,9 +155,9 @@ class ServeEngine:
     oracle read.  ``debug_invariants=True`` cross-checks the block tables
     against the pool free list before every decode tick.
 
-    ``compile_cache=`` (a directory, or True for the default — see
-    ``serve.prewarm.enable_compile_cache``) persists compiled executables
-    across processes; ``prewarm=True`` AOT-compiles the engine's complete
+    ``compile_cache=True`` persists compiled executables across processes
+    (in ``$JAX_COMPILATION_CACHE_DIR``, else the repo's ``.cache/xla`` —
+    see ``serve.prewarm.enable_compile_cache``); ``prewarm=True`` AOT-compiles the engine's complete
     executable set (``executable_shapes()``) before any request is
     admitted; ``strict_prewarm=True`` hard-errors on any compile inside
     the serving loop (the ``mid_serve_compiles == 0`` assertion mode)."""
@@ -170,13 +170,13 @@ class ServeEngine:
                  preempt: str = "replay", debug_invariants: bool = False,
                  mesh=None, tp_collective: str = "auto",
                  spec: Optional[SpecConfig] = None,
-                 compile_cache=None, prewarm: bool = False,
+                 compile_cache: bool = False, prewarm: bool = False,
                  strict_prewarm: bool = False):
         t_init = time.perf_counter()
         # cache config is process-global; set it before anything compiles so
         # the conversion/device_put jits below persist too
-        self.compile_cache_dir = (enable_compile_cache(compile_cache)
-                                  if compile_cache else None)
+        self.compile_cache_dir = (enable_compile_cache() if compile_cache
+                                  else None)
         self._compile_log = CompileLog(strict=strict_prewarm)
         self._jits: Dict[str, JitEntry] = {}
         if kv not in ("slotted", "paged"):

@@ -10,8 +10,9 @@ every TP mesh variant traces and compiles its own executable, and paying
 that lazily at first use turns cold start into minutes of XLA time on big
 configs.  This module moves all of it out of the serving loop:
 
-* ``enable_compile_cache`` wires ``jax``'s persistent compilation cache to a
-  repo-local directory, so every executable an engine ever built is reusable
+* ``enable_compile_cache`` turns on ``jax``'s persistent compilation cache:
+  in ``$JAX_COMPILATION_CACHE_DIR`` where that is set, else in the repo's
+  ``.cache/xla`` — so every executable an engine ever built is reusable
   across process restarts (and warmable in CI).
 
 * ``JitEntry`` wraps one engine jit entry point (decode / prefill / propose
@@ -53,28 +54,31 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-DEFAULT_CACHE_DIR = os.path.join(".cache", "xla")
-CACHE_ENV_VAR = "REPRO_COMPILE_CACHE"
+CACHE_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# anchored on this file, not the working directory: the cache path is part
+# of what makes an entry found again, so it must not move with the cwd
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    os.pardir, ".cache", "xla")
 
 
-def enable_compile_cache(path=None) -> str:
-    """Point jax's persistent compilation cache at a repo-local directory.
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache and return its directory.
 
-    ``path`` resolution: an explicit directory wins; ``True``/``"auto"``/
-    ``None`` fall back to ``$REPRO_COMPILE_CACHE`` and then to
-    ``.cache/xla`` under the current working directory (the repo root in
-    CI, where ``actions/cache`` persists it across runs).  The directory is
-    created if missing and the resolved absolute path returned.
+    Where ``$JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it as
+    its cache directory, and no directory is set here; otherwise the cache
+    lives in ``.cache/xla`` at the repository root (git-ignored, and what
+    CI persists with ``actions/cache``).
 
     The min-compile-time / min-entry-size gates are disabled: the serve
     jits on smoke configs compile in well under the default 1 s threshold,
     which would skip exactly the executables prewarm wants to persist.
     Safe to call repeatedly (jax config updates are idempotent)."""
-    if path in (None, True, "", "auto"):
-        path = os.environ.get(CACHE_ENV_VAR) or DEFAULT_CACHE_DIR
-    path = os.path.abspath(str(path))
+    path = os.environ.get(CACHE_ENV_VAR)
+    if not path:
+        path = os.path.normpath(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
     os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path

@@ -37,11 +37,14 @@ def child_env(devices: int) -> dict:
     return env
 
 
-def run_child(code: str, devices: int = 8, argv=(), timeout: int = 420) -> dict:
-    """Run ``code`` in a fresh interpreter; return its last stdout line as JSON."""
+def run_child(code: str, devices: int = 8, argv=(), timeout: int = 420,
+              env=None) -> dict:
+    """Run ``code`` in a fresh interpreter; return its last stdout line as
+    JSON.  ``env`` adds variables to the child's environment."""
     res = subprocess.run([sys.executable, "-c", code, *map(str, argv)],
                          capture_output=True, text=True,
-                         env=child_env(devices), timeout=timeout)
+                         env={**child_env(devices), **(env or {})},
+                         timeout=timeout)
     assert res.returncode == 0, res.stderr[-3000:]
     return json.loads(res.stdout.strip().splitlines()[-1])
 

@@ -32,7 +32,7 @@ def test_conv2d_sparse_pallas_interpret():
     x = jax.random.normal(jax.random.PRNGKey(2), (1, 8, 8, c_in))
     sp = sparse_conv_init(jax.random.PRNGKey(3), c_in, c_out, 3, 3, 1, 4)
     y_xla = conv2d_sparse(x, sp, 3, 3, impl="xla")
-    y_pl = conv2d_sparse(x, sp, 3, 3, impl="pallas_interpret")
+    y_pl = conv2d_sparse(x, sp, 3, 3, impl="pallas")
     np.testing.assert_allclose(np.asarray(y_pl), np.asarray(y_xla),
                                rtol=2e-4, atol=2e-4)
 

@@ -35,9 +35,9 @@ def test_constrain_divisibility_subprocess():
     code = r"""
 import json
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.dist.api import axis_rules, constrain, make_shardings
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 with axis_rules(mesh):
     # kv=3 doesn't divide model=4 -> dropped; batch=8 divides data=2 -> kept
     @jax.jit
@@ -91,8 +91,9 @@ def test_pipeline_parallel_subprocess():
     code = r"""
 import json
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.dist.pipeline import pipeline_apply
-mesh = jax.make_mesh((4,), ("pp",))
+mesh = jax.make_mesh((4,), ("pp",), axis_types=(AxisType.Auto,))
 S, M, MB, D = 4, 8, 2, 16
 ks = jax.random.split(jax.random.PRNGKey(0), S)
 params = jnp.stack([jax.random.normal(k, (D, D)) * 0.2 for k in ks])
@@ -123,6 +124,7 @@ def test_sharded_train_step_subprocess():
     code = r"""
 import json
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.dist.api import axis_rules, make_shardings
 from repro.launch import steps as steps_mod
@@ -139,7 +141,7 @@ batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.voc
 step = steps_mod.make_train_step(cfg, ocfg)
 p_ref, _, m_ref = jax.jit(step)(params, opt, batch, jnp.int32(0))
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 with axis_rules(mesh):
     step_sh = steps_mod.make_train_step(cfg, ocfg, param_specs=pspecs)
     psh = make_shardings(pspecs, mesh, shapes_tree=params)

@@ -6,6 +6,7 @@ from conftest import run_child
 _CODE = r"""
 import json, sys
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.dist.api import axis_rules, make_shardings
@@ -16,7 +17,8 @@ from repro.optim import AdamWConfig, adamw_init
 phase, ckpt_dir, ndev_data = sys.argv[1], sys.argv[2], int(sys.argv[3])
 cfg = get_config("llama3.2-1b", smoke=True).replace(n_layers=2, grad_accum=1)
 ocfg = AdamWConfig(master_weights=False)
-mesh = jax.make_mesh((ndev_data, 2), ("data", "model"))
+mesh = jax.make_mesh((ndev_data, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 mgr = CheckpointManager(ckpt_dir)
 
 with axis_rules(mesh):

@@ -60,16 +60,15 @@ def test_nm_spmm_kernel_matches_ref(nm, shape):
 
 
 @pytest.mark.parametrize("nm", NM)
-@pytest.mark.parametrize("mode", ["gather", "onehot"])
-def test_nm_spmv_kernel_matches_ref(nm, mode):
+@pytest.mark.parametrize("b", [4, 8])
+def test_nm_spmv_kernel_matches_ref(nm, b):
     n, m = nm
-    b, o, k = 4, 192, 512
+    o, k = 192, 512
     kw = jax.random.split(jax.random.PRNGKey(2))
     w = jax.random.normal(kw[0], (o, k), jnp.float32)
     x = jax.random.normal(kw[1], (b, k), jnp.float32)
     sp = compress(w, n, m)
-    y = kops.nm_spmv(x, sp.values, sp.indices, n, m, mode=mode,
-                     interpret=True)
+    y = kops.nm_spmv(x, sp.values, sp.indices, n, m, interpret=True)
     y_ref = kref.nm_spmv_ref(x, sp.values, sp.indices, n, m)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
@@ -221,13 +220,14 @@ def test_diff_spmm_kernel(nm, dtype, idx_stream):
 
 @pytest.mark.parametrize("nm", DIFF_NM)
 @pytest.mark.parametrize("dtype", DIFF_DTYPES)
-@pytest.mark.parametrize("mode", ["gather", "onehot"])
-def test_diff_spmv_kernel(nm, dtype, mode):
+@pytest.mark.parametrize("idx_stream", ["int8", "packed_roundtrip"])
+def test_diff_spmv_kernel(nm, dtype, idx_stream):
     n, m = nm
     o, k, b = 64, 160 if m == 8 else 256, 4
     x, sp = _diff_problem(n, m, o, k, b, dtype, seed=25)
-    idx = _storage_roundtrip(sp.indices, m, sp.nnz_per_row)
-    y = kops.nm_spmv(x, sp.values, idx, n, m, mode=mode, interpret=True)
+    idx = sp.indices if idx_stream == "int8" else \
+        _storage_roundtrip(sp.indices, m, sp.nnz_per_row)
+    y = kops.nm_spmv(x, sp.values, idx, n, m, interpret=True)
     y_ref = kref.nm_spmv_ref(x.astype(jnp.float32),
                              sp.values.astype(jnp.float32), idx, n, m)
     np.testing.assert_allclose(np.asarray(y, jnp.float32),
@@ -266,8 +266,8 @@ def test_select_impl_cpu_routes_decode_to_fused_decompress():
     # an explicitly pinned impl always wins over the shape policy
     assert select_impl(dataclasses.replace(cfg, impl="ref"), (4, 1, 256)) == "ref"
     # decode_impl pins only the decode side
-    pin = dataclasses.replace(cfg, decode_impl="spmv_interpret")
-    assert select_impl(pin, (4, 1, 256)) == "spmv_interpret"
+    pin = dataclasses.replace(cfg, decode_impl="spmv")
+    assert select_impl(pin, (4, 1, 256)) == "spmv"
     assert select_impl(pin, (4, 32, 256)) == default_impl((4, 32, 256))
 
 
@@ -278,11 +278,16 @@ def test_select_impl_tpu_routes_decode_to_spmv(monkeypatch):
     assert select_impl(cfg, (4, 1, 256)) == "spmv"       # seq-len 1: vindexmac
     assert select_impl(cfg, (4, 32, 256)) == "pallas"    # prefill: spmm tiles
     assert select_impl(cfg, (2, 256)) == "spmv"          # small-batch matvec
-    one = dataclasses.replace(cfg, spmv_mode="onehot")
-    assert select_impl(one, (4, 1, 256)) == "spmv_onehot"
+    # GSPMD cannot partition a Pallas call: under a multi-device mesh both
+    # shapes take the XLA formulation
+    from jax.sharding import AbstractMesh
+    from repro.dist.api import SERVE_TP_RULES, axis_rules
+    with axis_rules(AbstractMesh((4,), ("model",)), SERVE_TP_RULES):
+        assert select_impl(cfg, (4, 1, 256)) == "xla"
+        assert select_impl(cfg, (4, 32, 256)) == "xla"
 
 
-@pytest.mark.parametrize("impl", ["spmv_interpret"])
+@pytest.mark.parametrize("impl", ["spmv"])
 def test_nm_matmul_spmv_impl_matches_ref(impl):
     """The spmv impl names dispatch through nm_matmul to the decode kernel
     (leading dims flattened like every other route)."""
@@ -303,7 +308,7 @@ def test_decode_route_spmv_matches_tile_path():
     through the spmv kernel (interpret mode) agrees with the xla tile-path
     result for the same compressed params."""
     from repro.core.layers import linear_apply, linear_init
-    cfg = SparsityConfig(n=2, m=4, impl="auto", decode_impl="spmv_interpret",
+    cfg = SparsityConfig(n=2, m=4, impl="auto", decode_impl="spmv",
                          min_dim=64)
     p = linear_init(jax.random.PRNGKey(31), 256, 128,
                     dataclasses.replace(cfg, mode="compressed"),

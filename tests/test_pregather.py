@@ -9,6 +9,7 @@ def test_pregather_equivalence_subprocess():
     code = r"""
 import json
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.dist.api import axis_rules, make_shardings
 from repro.launch import steps as steps_mod
@@ -24,7 +25,7 @@ opt = adamw_init(params, ocfg)
 batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab),
          "labels": jax.random.randint(jax.random.PRNGKey(2), (8, 32), 0, cfg.vocab)}
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 outs = {}
 with axis_rules(mesh):
     psh = make_shardings(pspecs, mesh, shapes_tree=params)

@@ -206,18 +206,17 @@ def test_shape_key_ignores_dict_insertion_order():
 # ------------------------------------------------- persistent compile cache
 
 def test_warm_cache_bringup_strictly_faster(tmp_path):
-    """Two child processes prewarm the same config over one cache dir: the
-    second one's compile() calls are disk hits, so its bring-up must be
-    strictly faster than the first's."""
+    """Two child processes prewarm the same config over one cache dir (given
+    as ``$JAX_COMPILATION_CACHE_DIR``): the second one's compile() calls are
+    disk hits, so its bring-up must be strictly faster than the first's."""
     code = r"""
-import dataclasses, json, sys
+import dataclasses, json
 import jax, numpy as np
 from repro.configs import get_config
 from repro.models import init_model
 from repro.serve import ServeEngine, enable_compile_cache, synthetic_request
 
-cache_dir = sys.argv[1]
-enable_compile_cache(cache_dir)
+enable_compile_cache()
 cfg = get_config("llama3.2-1b", smoke=True)
 cfg = cfg.replace(sparsity=dataclasses.replace(
     cfg.sparsity, mode="compressed", impl="xla"))
@@ -232,9 +231,10 @@ print(json.dumps({"init_s": eng.stats()["init_seconds"],
                   "mid_serve": int(eng.stats()["mid_serve_compiles"])}))
 """
     cache = str(tmp_path / "xla")
-    cold = run_child(code, devices=1, argv=[cache])
+    env = {"JAX_COMPILATION_CACHE_DIR": cache}
+    cold = run_child(code, devices=1, env=env)
     assert os.listdir(cache), "persistent cache wrote nothing"
-    warm = run_child(code, devices=1, argv=[cache])
+    warm = run_child(code, devices=1, env=env)
     assert cold["mid_serve"] == warm["mid_serve"] == 0
     assert warm["tokens"] == cold["tokens"]
     assert warm["init_s"] < cold["init_s"], (cold, warm)

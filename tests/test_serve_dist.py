@@ -81,8 +81,10 @@ def test_slotted_tp_matches_oracle():
 
 
 def test_ring_linear_bitwise_and_lowering():
-    """dist.collectives.ring_sparse_linear == the local decompress path,
-    bitwise, and lowers to collective-permute with zero all-gathers."""
+    """dist.collectives.ring_sparse_linear == the local decompress path, and
+    lowers to collective-permute with zero all-gathers.  Equal to f32
+    rounding, not bit for bit: XLA's CPU dot may order the K reduction of a
+    [B, K] x [K, O/ndev] shard differently from the whole [K, O] weight."""
     code = r"""
 import json
 import numpy as np
@@ -106,13 +108,13 @@ y_ring = f(x, v, i)
 y_ref = _xwt_xla(x, sp.values, sp.indices, 2, 4, gather_compressed=False)
 hlo = f.lower(x, v, i).compile().as_text()
 print(json.dumps({
-    "bitwise": bool(np.array_equal(np.asarray(y_ring), np.asarray(y_ref))),
+    "max_err": float(np.abs(np.asarray(y_ring) - np.asarray(y_ref)).max()),
     "has_permute": "collective-permute" in hlo,
     "gathers": hlo.count(" all-gather("),
 }))
 """
     out = run_child(code, devices=4)
-    assert out["bitwise"], "ring must be bitwise-equal to the local path"
+    assert out["max_err"] <= 1e-5, f"ring diverged from the local path: {out}"
     assert out["has_permute"], "ring should lower to collective-permute"
     assert out["gathers"] == 0, "compressed operands must not be all-gathered"
 
