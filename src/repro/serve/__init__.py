@@ -46,6 +46,9 @@ one preallocated KV-cache tree) fed by a FCFS request queue:
   steady-state ticks never trace) plus per-executable compile accounting
   (``stats()["mid_serve_compiles"]`` et al., hard-asserted zero under
   ``strict_prewarm=True``).
+* ``trace``     — the engine's host spans: every decode tick, admission and
+  prompt replay, with its phases, in a bounded in-process ring and as
+  ``jax.profiler.TraceAnnotation``s on the device trace's clock.
 
 Relation to the paper
 ---------------------

@@ -99,6 +99,7 @@ full by admitting on bytes, not rows.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -111,6 +112,7 @@ from repro.models import (convert_to_compressed, decode_step, init_caches,
                           make_draft, param_shard_specs, prefill,
                           serve_ring_traffic_bytes, verify_step,
                           weight_stream_bytes)
+from repro.serve import trace
 from repro.serve.cache import scatter_slot, seed_decode_caches
 from repro.serve.paged import BlockPool, SwapState, TRASH_BLOCK, \
     _detect_layout, default_buckets
@@ -130,6 +132,10 @@ class _SlotState:
     # prompt tokens not yet fed (bucketed-down prefill catch-up); while
     # non-empty the slot is still consuming its prompt and emits nothing
     pending: List[int] = dataclasses.field(default_factory=list)
+    # prompt replay through decode ticks: when the admission that started
+    # it ended (perf_counter), and the prompt tokens fed so far
+    replay_from: float = math.nan
+    replayed: int = 0
 
 
 @dataclasses.dataclass
@@ -266,6 +272,9 @@ class ServeEngine:
         self.swap_ins = 0
         self.index_evictions = 0
         self.rejected = 0
+        self.replayed_prompt_tokens = 0      # prompt tokens fed by decode
+        # this engine's spans in the process-wide recorder (serve.trace)
+        self.trace_id = trace.new_engine()
         self.prefill_lengths = set()         # distinct compiled prefill seqs
         self._slots: Dict[int, _SlotState] = {}
         self._suspended: Dict[int, _Suspended] = {}   # rid -> host state
@@ -346,7 +355,6 @@ class ServeEngine:
             self._prefill = self._jit_entry(
                 "prefill", lambda p, b: prefill(p, cfg, b))
         self._exec_shapes = None
-        self._tick_wall: List[float] = []
         self.prewarmed = False
         self.prewarm_seconds = 0.0
         if prewarm:
@@ -585,19 +593,39 @@ class ServeEngine:
         """Install ``req`` into ``slot``.  Returns False when a paged
         admission backed out (the blocks the fits-gate sized against are
         gone by allocation time): the request is requeued at the queue
-        front, the slot freed, and the caller stops admitting this tick."""
-        if self.kv == "paged":
-            if req.rid in self._suspended:
-                return self._resume(slot, req, now)
-            return self._admit_paged(slot, req, now)
+        front, the slot freed, and the caller stops admitting this tick.
+
+        The whole admission is the ``serve.admit`` span (count:
+        ``positions`` prefilled); a request left replaying its prompt
+        through decode ticks starts its ``serve.replay`` span where this
+        one ends."""
+        with trace.span("serve.admit", engine=self.trace_id,
+                        rid=req.rid, positions=0) as sp:
+            if self.kv != "paged":
+                ok = self._admit_slotted(slot, req, now, sp)
+            elif req.rid in self._suspended:
+                ok = self._resume(slot, req, now)
+            else:
+                ok = self._admit_paged(slot, req, now, sp)
+        st = self._slots.get(slot)
+        if (ok and st is not None and st.req is req and not st.tokens
+                and math.isnan(st.replay_from)):
+            st.replay_from = sp.end
+        return ok
+
+    def _admit_slotted(self, slot: int, req: Request, now: int, sp) -> bool:
         self.prefill_lengths.add(req.prompt_len)
         self.prefill_calls += 1
-        batch = {k: jnp.asarray(v)[None] for k, v in req.inputs.items()}
-        logits, pf = self._prefill(self.params, batch)
-        single, _ = init_caches(self.cfg, 1, self.max_len)
-        single = seed_decode_caches(self.cfg, single, pf)
-        self.caches = scatter_slot(self.caches, single, slot)
-        first = int(jnp.argmax(logits[0]))
+        sp.counts["positions"] = req.prompt_len
+        with trace.span("serve.prefill"):
+            batch = {k: jnp.asarray(v)[None] for k, v in req.inputs.items()}
+            logits, pf = self._prefill(self.params, batch)
+        with trace.span("serve.seed"):
+            single, _ = init_caches(self.cfg, 1, self.max_len)
+            single = seed_decode_caches(self.cfg, single, pf)
+            self.caches = scatter_slot(self.caches, single, slot)
+        with trace.span("serve.sync"):
+            first = int(jnp.argmax(logits[0]))
         self._slots[slot] = _SlotState(req=req, tokens=[first],
                                        admitted_at=now)
         self.pos[slot] = req.prompt_len
@@ -607,7 +635,7 @@ class ServeEngine:
             self._retire(slot, now)
         return True
 
-    def _admit_paged(self, slot: int, req: Request, now: int) -> bool:
+    def _admit_paged(self, slot: int, req: Request, now: int, sp) -> bool:
         plen = req.prompt_len
         # prefix-cache hit: the shared span is already resident — point the
         # slot's table at the cached blocks (a table write, zero prefill)
@@ -636,27 +664,32 @@ class ServeEngine:
             # retry once retirements/evictions refill the free heap
             self.scheduler.preempt(slot)
             return False
-        # build the bucketed prefill batch: bucket-down truncates the token
-        # prompt (remainder replays through decode), pad-up right-pads the
-        # prompt itself (positions >= plen never reach earlier logits and
-        # are never seeded; encoder inputs are not positions, keep whole)
-        batch = {}
-        for k, v in req.inputs.items():
-            a = jnp.asarray(v)[None]
-            if k == "tokens" and not pad_up:
-                a = a[:, :pb]
-            elif pad_up and k != "enc_embeds" and pb > plen:
-                a = jnp.pad(a, ((0, 0), (0, pb - plen))
-                            + ((0, 0),) * (a.ndim - 2))
-            batch[k] = a
-        self.prefill_lengths.add(pb)
-        self.prefill_calls += 1
-        lp = (plen if pad_up else pb) - 1
-        logits, pf = self._prefill(self.params, batch,
-                                   jnp.asarray(lp, jnp.int32))
-        self.pool.seed(slot, pf, n_seed)
+        sp.counts["positions"] = pb
+        with trace.span("serve.prefill"):
+            # build the bucketed prefill batch: bucket-down truncates the
+            # token prompt (remainder replays through decode), pad-up
+            # right-pads the prompt itself (positions >= plen never reach
+            # earlier logits and are never seeded; encoder inputs are not
+            # positions, keep whole)
+            batch = {}
+            for k, v in req.inputs.items():
+                a = jnp.asarray(v)[None]
+                if k == "tokens" and not pad_up:
+                    a = a[:, :pb]
+                elif pad_up and k != "enc_embeds" and pb > plen:
+                    a = jnp.pad(a, ((0, 0), (0, pb - plen))
+                                + ((0, 0),) * (a.ndim - 2))
+                batch[k] = a
+            self.prefill_lengths.add(pb)
+            self.prefill_calls += 1
+            lp = (plen if pad_up else pb) - 1
+            logits, pf = self._prefill(self.params, batch,
+                                       jnp.asarray(lp, jnp.int32))
+        with trace.span("serve.seed"):
+            self.pool.seed(slot, pf, n_seed)
         if n_seed >= plen:                   # prompt fully prefilled
-            first = int(jnp.argmax(logits[0]))
+            with trace.span("serve.sync"):
+                first = int(jnp.argmax(logits[0]))
             st = _SlotState(req=req, tokens=[first], admitted_at=now)
             self.pos[slot] = plen
             self.tok[slot] = first
@@ -776,46 +809,85 @@ class ServeEngine:
     def step(self, now: int) -> None:
         """One batched decode tick over the pool (per-slot positions).
 
+        The tick is the ``serve.step`` span (count: ``rows`` decoded) and
+        runs in three phases, each a child span: ``serve.prepare`` backs
+        and copies-on-write every active slot's write block and builds the
+        inputs; ``serve.sync`` dispatches the decode and reads its tokens
+        back, the host blocked on the device; ``serve.commit`` appends the
+        tokens and retires finished requests.
+
         Occupancy is sampled HERE, after ``_prepare_slots`` has run its
         preemptions and only when a decode step actually executes — sampling
         before (as ``run`` once did) recorded phantom active slots on ticks
         whose slots all got preempted and counted ticks that decoded
         nothing."""
-        if self._spec is not None:
-            self._spec_step(now)
-            return
-        if self.kv == "paged":
-            self._prepare_slots(now)
-            if not self._slots:
-                return                       # everything was preempted
+        with trace.span("serve.step", engine=self.trace_id, rows=0) as sp:
+            if self._spec is not None:
+                self._spec_step(now, sp)
+            else:
+                self._decode_tick(now, sp)
+
+    def _decode_tick(self, now: int, sp) -> None:
+        paged = self.kv == "paged"
+        with trace.span("serve.prepare"):
+            if paged:
+                self._prepare_slots(now)
+                if not self._slots:
+                    return                   # everything was preempted
             self.scheduler.record_occupancy()
-            if self.debug_invariants:
+            if paged and self.debug_invariants:
                 # the fused kernel reads exactly the blocks the table names:
                 # prove every active slot's read window is backed by owned,
                 # non-free, non-trash blocks — and its write block exclusive
                 # (COW ran) — before launching it
                 self.check_invariants(
                     active_pos={s: int(self.pos[s]) for s in self._slots})
-            logits, self.pool.caches = self._decode(
-                self.params, self.pool.caches, jnp.asarray(self.tok),
-                jnp.asarray(self.pos), self.pool.device_table())
-        else:
-            self.scheduler.record_occupancy()
-            logits, self.caches = self._decode(
-                self.params, self.caches, jnp.asarray(self.tok),
-                jnp.asarray(self.pos))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            args = (jnp.asarray(self.tok), jnp.asarray(self.pos))
+            if paged:
+                args += (self.pool.device_table(),)
+        sp.counts["rows"] = len(self._slots)
+        with trace.span("serve.sync") as sync:
+            if paged:
+                logits, self.pool.caches = self._decode(
+                    self.params, self.pool.caches, *args)
+            else:
+                logits, self.caches = self._decode(
+                    self.params, self.caches, *args)
+            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         self.decode_steps += 1
-        for slot in list(self._slots):
-            st = self._slots[slot]
-            self.pos[slot] += 1
-            if st.pending:                   # still consuming the prompt
-                self.tok[slot] = st.pending.pop(0)
-                continue
-            st.tokens.append(int(nxt[slot]))
-            self.tok[slot] = nxt[slot]
-            if len(st.tokens) >= st.req.max_new_tokens:
-                self._retire(slot, now)
+        with trace.span("serve.commit"):
+            for slot in list(self._slots):
+                self._advance(slot, int(nxt[slot]), now, sync.end)
+
+    def _advance(self, slot: int, token: int, now: int, t: float) -> None:
+        """Move ``slot`` one position on after a tick fed it ``self.tok``:
+        while its prompt replays it takes the next prompt token, otherwise
+        it emits ``token`` (on the host since ``t``)."""
+        st = self._slots[slot]
+        self.pos[slot] += 1
+        if not st.tokens:                    # the tick fed a prompt token
+            self._fed_prompt(st, 1)
+        if st.pending:                       # still consuming the prompt
+            self.tok[slot] = st.pending.pop(0)
+            return
+        st.tokens.append(token)
+        self.tok[slot] = token
+        if len(st.tokens) == 1:
+            self._replay_done(st, t)
+        if len(st.tokens) >= st.req.max_new_tokens:
+            self._retire(slot, now)
+
+    def _fed_prompt(self, st: _SlotState, n: int) -> None:
+        st.replayed += n
+        self.replayed_prompt_tokens += n
+
+    def _replay_done(self, st: _SlotState, t: float) -> None:
+        """The request's first token came from a decode tick: record its
+        prompt replay, admission's end to ``t``, as ``serve.replay``."""
+        if st.replayed and not math.isnan(st.replay_from):
+            trace.record("serve.replay", st.replay_from, t,
+                         engine=self.trace_id, rid=st.req.rid,
+                         tokens=st.replayed)
 
     # -------------------------------------------------- speculative decoding
 
@@ -835,7 +907,7 @@ class ServeEngine:
                 tok[s] = 0
         return jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tbl)
 
-    def _spec_step(self, now: int) -> None:
+    def _spec_step(self, now: int, sp) -> None:
         """One speculative tick: a plain decode forward for the
         non-speculating slots, then one draft-propose + target-verify round
         for the speculating ones — each forward runs over the full pool
@@ -856,54 +928,51 @@ class ServeEngine:
         consumed position, freeing span blocks past it."""
         k = self._spec.k
         cap = self.pool.table_width * self.pool.block_size
-        draft_set, forced_set = set(), set()
-        for slot, st in self._slots.items():
-            on = (st.req.spec if st.req.spec is not None
-                  else self._spec.default_on)
-            if not on or int(self.pos[slot]) + k >= cap:
-                continue
-            if st.pending:
-                forced_set.add(slot)
-            elif st.req.max_new_tokens - len(st.tokens) >= 2:
-                draft_set.add(slot)
-        spec_set = draft_set | forced_set
-        self._prepare_slots(now, spec_set)
-        if not self._slots:
-            return                           # everything was preempted
-        spec_set &= set(self._slots)
-        draft_set &= spec_set
-        forced_set &= spec_set
-        plain = set(self._slots) - spec_set
-        if self.debug_invariants:
-            self.check_invariants(active_pos={
-                s: int(self.pos[s]) + (k if s in spec_set else 0)
-                for s in self._slots})
+        with trace.span("serve.prepare"):
+            draft_set, forced_set = set(), set()
+            for slot, st in self._slots.items():
+                on = (st.req.spec if st.req.spec is not None
+                      else self._spec.default_on)
+                if not on or int(self.pos[slot]) + k >= cap:
+                    continue
+                if st.pending:
+                    forced_set.add(slot)
+                elif st.req.max_new_tokens - len(st.tokens) >= 2:
+                    draft_set.add(slot)
+            spec_set = draft_set | forced_set
+            self._prepare_slots(now, spec_set)
+            if not self._slots:
+                return                       # everything was preempted
+            spec_set &= set(self._slots)
+            draft_set &= spec_set
+            forced_set &= spec_set
+            plain = set(self._slots) - spec_set
+            if self.debug_invariants:
+                self.check_invariants(active_pos={
+                    s: int(self.pos[s]) + (k if s in spec_set else 0)
+                    for s in self._slots})
+        sp.counts["rows"] = len(self._slots)
         if plain:
             tok, pos, tbl = self._masked(plain)
-            logits, self.pool.caches = self._decode(
-                self.params, self.pool.caches, tok, pos, tbl)
-            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            with trace.span("serve.sync") as sync:
+                logits, self.pool.caches = self._decode(
+                    self.params, self.pool.caches, tok, pos, tbl)
+                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
             self.decode_steps += 1
             self.scheduler.record_occupancy()
-            for slot in sorted(plain):
-                st = self._slots[slot]
-                self.pos[slot] += 1
-                if st.pending:               # still consuming the prompt
-                    self.tok[slot] = st.pending.pop(0)
-                    continue
-                st.tokens.append(int(nxt[slot]))
-                self.tok[slot] = nxt[slot]
-                if len(st.tokens) >= st.req.max_new_tokens:
-                    self._retire(slot, now)
+            with trace.span("serve.commit"):
+                for slot in sorted(plain):
+                    self._advance(slot, int(nxt[slot]), now, sync.end)
         if not spec_set:
             return
         span = np.zeros((self.n_slots, k + 1), np.int32)
         span[:, 0] = self.tok
         if draft_set:
             tok, pos, tbl = self._masked(draft_set)
-            drafts, self.pool.caches = self._propose(
-                self._draft_params, self.pool.caches, tok, pos, tbl)
-            span[:, 1:] = np.asarray(drafts, np.int32)
+            with trace.span("serve.sync"):
+                drafts, self.pool.caches = self._propose(
+                    self._draft_params, self.pool.caches, tok, pos, tbl)
+                span[:, 1:] = np.asarray(drafts, np.int32)
             self.draft_steps += k
         # forced rows: the next prompt tokens ride the span in place of
         # drafts — acceptance is structural (the oracle consumes them
@@ -914,40 +983,55 @@ class ServeEngine:
             span[slot, 1:] = 0
             span[slot, 1:1 + f] = pend[:f]
         tok, pos, tbl = self._masked(spec_set)
-        vlogits, self.pool.caches = self._verify(
-            self.params, self.pool.caches, jnp.asarray(span), pos, tbl)
-        va = np.asarray(jnp.argmax(vlogits, axis=-1), np.int32)  # [B, k+1]
+        with trace.span("serve.sync") as sync:
+            vlogits, self.pool.caches = self._verify(
+                self.params, self.pool.caches, jnp.asarray(span), pos, tbl)
+            va = np.asarray(jnp.argmax(vlogits, axis=-1), np.int32)  # [B,k+1]
         self.decode_steps += 1
         self.scheduler.record_occupancy()
         acc = accept_greedy(span[:, 1:], va)
-        for slot in sorted(spec_set):
-            st = self._slots[slot]
-            if slot in forced_set:
-                f = min(k, len(st.pending))
-                del st.pending[:f]
-                self.pos[slot] += f + 1
-                if st.pending:               # prompt not done: no emission
-                    self.tok[slot] = st.pending.pop(0)
-                else:                        # first post-prompt emission
-                    st.tokens.append(int(va[slot, f]))
-                    self.tok[slot] = int(va[slot, f])
-                self.steps_saved += f
-            else:
-                budget = st.req.max_new_tokens - len(st.tokens)
-                n_commit = min(int(acc[slot]) + 1, budget)
-                commit = [int(t) for t in va[slot, :n_commit]]
-                st.tokens.extend(commit)
-                self.pos[slot] += n_commit
-                self.tok[slot] = commit[-1]
-                self.spec_proposed += k
-                self.spec_accepted += int(acc[slot])
-                self.steps_saved += n_commit - 1
-            # rewind: keep blocks backing the consumed positions, free the
-            # span tail (exclusive by _prepare_slots, so this can never
-            # take a block out from under another table)
-            self.pool.rollback(slot, int(self.pos[slot]))
-            if st.tokens and len(st.tokens) >= st.req.max_new_tokens:
-                self._retire(slot, now)
+        with trace.span("serve.commit"):
+            for slot in sorted(spec_set):
+                self._commit_span(slot, slot in forced_set, va[slot],
+                                  int(acc[slot]), now, sync.end)
+
+    def _commit_span(self, slot: int, forced: bool, va, acc: int, now: int,
+                     t: float) -> None:
+        """Commit one verified k+1 span of ``slot``: ``va`` is the target's
+        argmax at each span position, ``acc`` the drafts it accepted."""
+        k = self._spec.k
+        st = self._slots[slot]
+        if forced:
+            f = min(k, len(st.pending))
+            del st.pending[:f]
+            self.pos[slot] += f + 1
+            self._fed_prompt(st, f + 1)
+            if st.pending:                   # prompt not done: no emission
+                self.tok[slot] = st.pending.pop(0)
+            else:                            # first post-prompt emission
+                st.tokens.append(int(va[f]))
+                self.tok[slot] = int(va[f])
+                self._replay_done(st, t)
+            self.steps_saved += f
+        else:
+            budget = st.req.max_new_tokens - len(st.tokens)
+            n_commit = min(acc + 1, budget)
+            commit = [int(x) for x in va[:n_commit]]
+            if not st.tokens:                # the span began on the prompt's
+                self._fed_prompt(st, 1)      # last token: replay is over
+                self._replay_done(st, t)
+            st.tokens.extend(commit)
+            self.pos[slot] += n_commit
+            self.tok[slot] = commit[-1]
+            self.spec_proposed += k
+            self.spec_accepted += acc
+            self.steps_saved += n_commit - 1
+        # rewind: keep blocks backing the consumed positions, free the
+        # span tail (exclusive by _prepare_slots, so this can never
+        # take a block out from under another table)
+        self.pool.rollback(slot, int(self.pos[slot]))
+        if st.tokens and len(st.tokens) >= st.req.max_new_tokens:
+            self._retire(slot, now)
 
     # -------------------------------------------------------------- main loop
 
@@ -972,12 +1056,7 @@ class ServeEngine:
                 for slot, req in self.scheduler.admit(t):
                     self._admit(slot, req, t)
             if self.active.any():
-                t0 = time.perf_counter()
                 self.step(t)                 # samples occupancy iff it decodes
-                # step() reads the logits to host, so the wall time below is
-                # synchronous — the cold/warm tick observability behind
-                # stats()["first_tick_s"] / ["steady_tick_s"]
-                self._tick_wall.append(time.perf_counter() - t0)
             t += 1
         self.ticks = t
         return self.results
@@ -995,6 +1074,8 @@ class ServeEngine:
         toks = sum(len(r.tokens) for r in self.results.values())
         ws = self.weight_stream
         log = self._compile_log
+        ticks = [s.dur for s in trace.spans(engine=self.trace_id,
+                                             name="serve.step")]
         out = {"decode_steps": float(self.decode_steps),
                "occupancy": self.scheduler.occupancy(),
                "tokens": float(toks),
@@ -1014,10 +1095,12 @@ class ServeEngine:
                                        for j in self._jits.values())),
                "executables_expected": float(
                    self.executable_shapes()["total"]),
-               "first_tick_s": float(self._tick_wall[0]
-                                     if self._tick_wall else 0.0),
-               "steady_tick_s": float(np.median(self._tick_wall[1:])
-                                      if len(self._tick_wall) > 1 else 0.0),
+               # wall time of this engine's decode ticks (serve.step spans;
+               # a tick reads its tokens back, so each is synchronous)
+               "first_tick_s": float(ticks[0] if ticks else 0.0),
+               "steady_tick_s": float(np.median(ticks[1:])
+                                      if len(ticks) > 1 else 0.0),
+               "replayed_prompt_tokens": float(self.replayed_prompt_tokens),
                "prefill_calls": float(self.prefill_calls),
                "rejected": float(self.rejected),
                # per-decode-step weight-stream traffic (every step re-reads
