@@ -152,9 +152,13 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ``table[slot, j]`` to a physical [block_size, D] tile and the pipeline DMAs
 # exactly the blocks a slot owns, while m/l/acc online-softmax state carries
 # across the kv-block grid axis (same formulation as _fa_body above).  The
-# trailing partial block is masked against ``kv_len`` — positions at and
-# beyond a slot's length (including everything a trash-block tile holds)
-# contribute exp(-inf) = 0.
+# grid spans the whole table.  In ``paged_gqa_decode`` a step whose block
+# holds no valid position of its slot neither fetches nor computes: its index
+# map clamps to the slot's last (or, windowed, first) valid block, so the
+# pipeline sees an unchanged block index and issues no DMA, and its body is
+# skipped — such a block would contribute exp(-inf) = 0.  ``paged_mla_decode``
+# still fetches and masks every table column.  Inside a block, positions at
+# and beyond ``kv_len`` (and, windowed, before the window) are masked.
 
 
 def _paged_gqa_body(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -170,35 +174,53 @@ def _paged_gqa_body(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)                  # [KVH*G, KVH*d]
-    k = k_ref[0].astype(jnp.float32)                  # [bs, KVH*d]
-    v = v_ref[0].astype(jnp.float32)                  # [bs, KVH*dv]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32,
-                            precision=_F32) * scale
-    if cap is not None:
-        s = cap * jnp.tanh(s / cap)
-
     kv_len = len_ref[b]
-    kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = kpos < kv_len
+    live = j * bs < kv_len
     if window is not None:
-        mask &= kpos > kv_len - 1 - window
-    s = jnp.where(mask, s, _NEG)
+        live &= (j + 1) * bs > kv_len - window
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        precision=_F32)
-    m_ref[...] = m_new
+    @pl.when(live)
+    def _accumulate():
+        q = q_ref[0].astype(jnp.float32)              # [KVH*G, KVH*d]
+        k = k_ref[0].astype(jnp.float32)              # [bs, KVH*d]
+        v = v_ref[0].astype(jnp.float32)              # [bs, KVH*dv]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                                precision=_F32) * scale
+        if cap is not None:
+            s = cap * jnp.tanh(s / cap)
+
+        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        mask = kpos < kv_len
+        if window is not None:
+            mask &= kpos > kv_len - 1 - window
+        s = jnp.where(mask, s, _NEG)
+
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
+        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=_F32)
+        m_ref[...] = m_new
 
     @pl.when(j == t_steps - 1)
     def _store():
         o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
+
+
+def _live_block(j, kv_len, bs: int, window: Optional[int]):
+    """Table column to fetch at grid step ``j``: ``j`` clamped to the
+    columns that hold a valid position of a slot with ``kv_len`` positions
+    (the last ``window`` of them, windowed).  A step outside that range then
+    names the same block as its neighbour, and the pipeline skips its DMA."""
+    last = jnp.maximum((kv_len - 1) // bs, 0)
+    if window is None:
+        return jnp.minimum(j, last)
+    first = jnp.maximum(kv_len - window, 0) // bs
+    return jnp.minimum(jnp.maximum(j, first), last)
 
 
 def paged_gqa_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -215,10 +237,14 @@ def paged_gqa_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     Grid (B, T): kv blocks are the innermost sequential axis; block j of
     slot b is fetched from physical block ``block_table[b, j]`` via the
     scalar-prefetched index map, so only pool blocks a slot's table names are
-    ever read (trash-block tiles beyond ``kv_len`` are fetched but fully
-    masked).  One tile holds the block's tokens for every kv head — the pool
-    viewed as [n_blocks, bs, KVH*d], a free reshape — which keeps the tile's
-    last two dims whole, as Mosaic requires.  The heads are scored in one
+    ever read.  Only the blocks that hold a valid position of the slot
+    (``ceil(kv_len / bs)`` of them, fewer under ``window``) are fetched and
+    computed: the index map clamps every other step to a live block, which
+    the pipeline does not fetch again, and the body skips it, so table
+    entries past ``kv_len`` (trash, or stale) are never read.  One tile
+    holds the block's tokens for every kv head — the pool viewed as
+    [n_blocks, bs, KVH*d], a free reshape — which keeps the tile's last two
+    dims whole, as Mosaic requires.  The heads are scored in one
     MXU pass with a block-diagonal query: row (h, g) carries q[h, g] in the
     lanes of kv head h and zeros elsewhere, so its scores and its slice of
     the context see only head h.  The context rows come back [KVH*G,
@@ -233,15 +259,16 @@ def paged_gqa_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     q_bd = (q[:, :, :, None, :] * eye[None, :, None, :, None]).reshape(
         b, rows, kvh * d)
 
+    def kv_map(b, j, tbl, lens):
+        return (tbl[b, _live_block(j, lens[b], bs, window)], 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, t),
         in_specs=[
             pl.BlockSpec((1, rows, kvh * d), lambda b, j, tbl, lens: (b, 0, 0)),
-            pl.BlockSpec((1, bs, kvh * d),
-                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0)),
-            pl.BlockSpec((1, bs, kvh * dv),
-                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, bs, kvh * d), kv_map),
+            pl.BlockSpec((1, bs, kvh * dv), kv_map),
         ],
         out_specs=pl.BlockSpec((1, rows, kvh * dv),
                                lambda b, j, tbl, lens: (b, 0, 0)),

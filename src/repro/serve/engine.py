@@ -809,8 +809,12 @@ class ServeEngine:
     def step(self, now: int) -> None:
         """One batched decode tick over the pool (per-slot positions).
 
-        The tick is the ``serve.step`` span (count: ``rows`` decoded) and
-        runs in three phases, each a child span: ``serve.prepare`` backs
+        The tick is the ``serve.step`` span (counts: ``rows`` decoded; on
+        the plain paged tick also ``kv_blocks``, the table columns the fused
+        attention kernel computes per layer, ``ceil((pos + 1) / block_size)``
+        summed over every slot, idle ones included, and ``table_blocks``,
+        the columns its grid spans, slots times table width) and runs in
+        three phases, each a child span: ``serve.prepare`` backs
         and copies-on-write every active slot's write block and builds the
         inputs; ``serve.sync`` dispatches the decode and reads its tokens
         back, the host blocked on the device; ``serve.commit`` appends the
@@ -846,6 +850,10 @@ class ServeEngine:
             if paged:
                 args += (self.pool.device_table(),)
         sp.counts["rows"] = len(self._slots)
+        if paged:
+            bs = self.pool.block_size
+            sp.counts["kv_blocks"] = int(((self.pos + bs) // bs).sum())
+            sp.counts["table_blocks"] = self.n_slots * self.pool.table_width
         with trace.span("serve.sync") as sync:
             if paged:
                 logits, self.pool.caches = self._decode(

@@ -7,7 +7,9 @@ Oracle hierarchy, weakest to strongest claim:
      swept over {GQA, MLA} x {block_size 8/16} x {f32, bf16} x ragged
      ``kv_len`` (single token, len < block_size, len exactly on a block
      boundary, full span), with window/softcap variants and hot trash
-     blocks (big finite garbage the mask must zero out).
+     blocks (big finite garbage the mask must zero out); for GQA also with
+     NaN in every block outside a slot's valid range, which the kernel
+     must not read at all.
   2. **fused engine vs gather engine** — same paged ServeEngine, only the
      read path differs: tokens must be identical (matched batch composition,
      so this also holds for the row-coupled MoE/MLA family).
@@ -34,7 +36,7 @@ except ImportError:                     # minimal env: keep the deterministic
     from conftest import given, settings, st   # tests, skip the property ones
 
 from repro.configs import get_config
-from repro.kernels.flash_attention import (paged_decode_traffic,
+from repro.kernels.flash_attention import (_live_block, paged_decode_traffic,
                                            paged_gqa_decode, paged_mla_decode)
 from repro.models import init_model
 from repro.models.common import softcap
@@ -105,8 +107,22 @@ def _gqa_gather_oracle(q, kp, vp, tbl, lens, scale, window=None, cap=None):
 
 
 # ragged kv lengths, all the block-boundary edges for bs in {8, 16}:
-# single token, len < bs, len exactly bs (boundary), bs + 1, full span
-_LENS = (1, 7, 8, 9, 16, 31, 32)
+# single token, len < bs, len exactly bs (boundary), bs + 1, a slot with
+# table columns to spare, and one at the full table width
+_LENS = (1, 7, 8, 9, 16, 31, 32, 48)
+
+
+def _dead_blocks(tbl, lens, bs, window=None):
+    """Pool blocks the table names outside each slot's valid block range:
+    past its last valid block, and before its first under ``window``."""
+    dead = []
+    for r, ln in enumerate(lens):
+        for j in range(tbl.shape[1]):
+            live = j * bs < ln and (window is None
+                                    or (j + 1) * bs > ln - window)
+            if not live:
+                dead.append(int(tbl[r, j]))
+    return np.unique(np.asarray(dead, np.int32))
 
 
 @pytest.mark.parametrize("bs", [8, 16])
@@ -116,8 +132,15 @@ _LENS = (1, 7, 8, 9, 16, 31, 32)
     dict(window=6),
     dict(cap=20.0),
     dict(window=9, cap=20.0),
+    dict(poison=True),
+    dict(window=6, poison=True),
+    dict(cap=20.0, poison=True),
+    dict(window=9, cap=20.0, poison=True),
 ])
 def test_paged_gqa_kernel_matches_gather_oracle(bs, dtype, case):
+    """With ``poison`` every block the table names outside a slot's valid
+    range holds NaN: the kernel must never read it, so its output stays
+    finite and identical to the same call on the clean pool."""
     b, kvh, g, d = len(_LENS), 2, 2, 32
     max_len = max(_LENS)
     tw = -(-max_len // bs)
@@ -128,13 +151,40 @@ def test_paged_gqa_kernel_matches_gather_oracle(bs, dtype, case):
     kp, vp = _gqa_pools(rng, n_blocks, bs, kvh, d, d, dtype)
     q = jnp.asarray(rng.standard_normal((b, kvh, g, d)), dtype)
     scale = d ** -0.5
-    out = jax.jit(lambda *a: paged_gqa_decode(
+    decode = jax.jit(lambda *a: paged_gqa_decode(
         *a, scale=scale, window=case.get("window"), cap=case.get("cap"),
-        interpret=True))(q, kp, vp, tbl, lens)
+        interpret=True))
+    out = decode(q, kp, vp, tbl, lens)
+    if case.get("poison"):
+        dead = _dead_blocks(np.asarray(tbl), _LENS, bs, case.get("window"))
+        assert TRASH_BLOCK in dead
+        clean = out
+        out = decode(q, kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan),
+                     tbl, lens)
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
     ref = _gqa_gather_oracle(q, kp, vp, tbl, lens, scale,
                              window=case.get("window"), cap=case.get("cap"))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("bs", [8, 16])
+@pytest.mark.parametrize("window", [None, 6, 9, 20])
+def test_paged_gqa_fetches_only_live_columns(bs, window):
+    """The K/V index map names a live column at every grid step: the step's
+    own where it is live, else the nearest live one, so the pipeline's
+    block index does not change across the dead steps."""
+    tw = -(-max(_LENS) // bs)
+    for ln in (0,) + _LENS:
+        live = [j for j in range(tw) if j * bs < ln and (
+            window is None or (j + 1) * bs > ln - window)]
+        got = [int(_live_block(jnp.int32(j), jnp.int32(ln), bs, window))
+               for j in range(tw)]
+        if not live:                     # no context: column 0, never read
+            assert got == [0] * tw
+            continue
+        assert got == [min(max(j, live[0]), live[-1]) for j in range(tw)]
 
 
 @pytest.mark.parametrize("bs", [8, 16])

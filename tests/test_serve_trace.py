@@ -1,8 +1,9 @@
 """The serving engine's host spans (``serve.trace``) on a paged smoke run:
-one ``serve.step`` per decode tick with its three phases, one
-``serve.admit`` per admission, a ``serve.replay`` per request whose prompt
-replays through decode ticks, the replayed-token counter, the bounded ring,
-and spans of two engines in one process kept apart."""
+one ``serve.step`` per decode tick with its three phases and its counts of
+attention blocks, one ``serve.admit`` per admission, a ``serve.replay`` per
+request whose prompt replays through decode ticks, the replayed-token
+counter, the bounded ring, and spans of two engines in one process kept
+apart."""
 
 import dataclasses
 
@@ -44,11 +45,12 @@ def _requests(cfg):
             for i, (p, g) in enumerate(zip(PLENS, GENS))]
 
 
-def drive(eng, reqs):
+def drive(eng, reqs, positions=None):
     """``ServeEngine.run``'s admit-then-step loop, counting for each request
     the decode ticks before its first token, as the benchmark's open
     loop counts them (a slot that has emitted nothing is replaying its
-    prompt)."""
+    prompt).  ``positions``, a list, collects every slot's position as
+    each tick starts."""
     for r in reqs:
         eng.submit(r)
     replay_ticks = {r.rid: 0 for r in reqs}
@@ -63,6 +65,8 @@ def drive(eng, reqs):
             for st in eng._slots.values():
                 if not st.tokens:
                     replay_ticks[st.req.rid] += 1
+            if positions is not None:
+                positions.append(eng.pos.copy())
             eng.step(t)
         t += 1
     return replay_ticks
@@ -100,6 +104,25 @@ def test_one_step_span_per_tick_with_its_three_phases(served):
     assert st["first_tick_s"] == pytest.approx(steps[0].dur)
     assert st["steady_tick_s"] == pytest.approx(
         float(np.median([s.dur for s in steps[1:]])))
+
+
+def test_step_spans_count_the_attention_blocks_with_context(model):
+    """``kv_blocks`` is the table columns holding context, one slot of
+    ``pos + 1`` positions at ``ceil((pos + 1) / block_size)``, idle slots
+    (position 0) at one; ``table_blocks`` is the whole table."""
+    eng = _engine(model)
+    positions = []
+    drive(eng, _requests(model[0]), positions)
+    assert eng.preemptions == 0
+    steps = [s for s in trace.spans(engine=eng.trace_id)
+             if s.name == "serve.step"]
+    assert len(steps) == len(positions) > 0
+    bs, width = eng.pool.block_size, eng.pool.table_width
+    for s, pos in zip(steps, positions):
+        assert s.counts["kv_blocks"] == sum(-(-(int(p) + 1) // bs)
+                                            for p in pos)
+        assert s.counts["table_blocks"] == eng.n_slots * width == 2 * 8
+    assert len({s.counts["kv_blocks"] for s in steps}) > 1
 
 
 def test_one_admit_span_per_admission_with_its_rid(served):

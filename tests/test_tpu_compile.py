@@ -7,8 +7,10 @@ interpret mode cannot — unaligned tiles, unsupported in-kernel reshapes or
 gathers, block shapes that break the (8, 128) rule — at no chip time.
 
 Widths: llama3.2-1b decode and prefill (K 2048, O 8192, d_ff 8192, batch 8,
-block size 8, KVH 8, G 4, d 64) in bf16, and deepseek-v2-lite's MLA decode
-(H 16, kv_lora 512, rope 64).  The topology is described inside a fixture,
+block size 8, KVH 8, G 4, d 64) in bf16, the benchmark's paged GQA decode
+at deepseek-67b's width (64 slots, KVH 8, G 8, d 128, a table of 80 blocks
+of 16, a pool of 3072), and deepseek-v2-lite's MLA decode (H 16, kv_lora
+512, rope 64).  The topology is described inside a fixture,
 never at import: only one process may hold the TPU library, and every test
 worker imports this file.
 """
@@ -103,25 +105,33 @@ def test_compress_stacked_weights(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * w_bytes
 
 
-def _gqa_args(sharding, q_shape):
-    pool = _sds(sharding, (B * T + 1, BS, KVH, D), jnp.bfloat16)
-    return (_sds(sharding, q_shape, jnp.bfloat16), pool, pool,
-            _sds(sharding, (B, T), jnp.int32), _sds(sharding, (B,), jnp.int32))
+def _gqa_args(sharding, span=(), b=B, kvh=KVH, g=G, d=D, bs=BS, t=T,
+              blocks=B * T + 1):
+    pool = _sds(sharding, (blocks, bs, kvh, d), jnp.bfloat16)
+    return (_sds(sharding, (b,) + span + (kvh, g, d), jnp.bfloat16), pool,
+            pool, _sds(sharding, (b, t), jnp.int32),
+            _sds(sharding, (b,), jnp.int32))
 
 
 def test_paged_gqa_decode(one_chip):
     _assert_kernel(lambda *a: paged_gqa_decode(*a, scale=D ** -0.5),
-                   *_gqa_args(one_chip, (B, KVH, G, D)))
+                   *_gqa_args(one_chip))
+
+
+def test_paged_gqa_decode_at_benchmark_width(one_chip):
+    _assert_kernel(lambda *a: paged_gqa_decode(*a, scale=128 ** -0.5),
+                   *_gqa_args(one_chip, b=64, kvh=8, g=8, d=128, bs=16, t=80,
+                              blocks=3072))
 
 
 def test_paged_gqa_decode_windowed_softcapped(one_chip):
     _assert_kernel(lambda *a: paged_gqa_decode(*a, window=256, cap=50.0),
-                   *_gqa_args(one_chip, (B, KVH, G, D)))
+                   *_gqa_args(one_chip))
 
 
 def test_paged_gqa_verify(one_chip):
     _assert_kernel(lambda *a: paged_gqa_verify(*a, scale=D ** -0.5),
-                   *_gqa_args(one_chip, (B, SPAN, KVH, G, D)))
+                   *_gqa_args(one_chip, span=(SPAN,)))
 
 
 def _mla_args(sharding, lead):
