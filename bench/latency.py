@@ -3,9 +3,10 @@
 - ``ttft_p90_ms``: 90th percentile, over the requests that arrived inside
   the window, of scheduled arrival to first token.  A request that failed
   or never finished counts as infinitely late.
-- ``itl_p50_ms`` / ``itl_p99_ms``: median and 99th percentile of the gaps
-  between successive tokens of one request, over every gap of every request
-  that arrived inside the window (followed to its end after the close).
+- ``itl_p50_ms`` / ``itl_p95_ms`` / ``itl_p99_ms``: median, 95th and 99th
+  percentile of the gaps between successive tokens of one request, over
+  every gap of every request that arrived inside the window (followed to
+  its end after the close).
 - ``output_tokens_per_s``: tokens delivered inside the window, over its
   length.
 
@@ -54,5 +55,6 @@ def end_to_end(log) -> Dict[str, float]:
                     if w0 <= t < w1)
     return {"ttft_p90_ms": 1e3 * percentile(ttfts(recs), 90),
             "itl_p50_ms": 1e3 * percentile(g, 50),
+            "itl_p95_ms": 1e3 * percentile(g, 95),
             "itl_p99_ms": 1e3 * percentile(g, 99),
             "output_tokens_per_s": delivered / (w1 - w0)}

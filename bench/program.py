@@ -2,10 +2,10 @@
 
 Everything the benchmark takes from ``repro`` goes through here: the
 model configuration, the compressed parameter tree (built from the
-benchmark's own dense weights by the program's packing pass) and the
-serving engine, built as the main serving path runs: compressed 2:4
-linears, paged KV, fused paged attention, prewarmed buckets, no compile
-while serving.
+benchmark's own dense weights by the program's packing pass, laid out by
+the model's family in ``bench/families``) and the serving engine, built
+as the main serving path runs: compressed 2:4 linears, paged KV, fused
+paged attention, prewarmed buckets, no compile while serving.
 """
 
 from __future__ import annotations
@@ -14,36 +14,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
-
-# program ArchConfig field <- configuration-file key
-_FIELDS = {"n_layers": "num_hidden_layers", "d_model": "hidden_size",
-           "n_heads": "num_attention_heads", "n_kv": "num_key_value_heads",
-           "d_ff": "intermediate_size", "vocab": "vocab_size",
-           "head_dim": "head_dim", "rope_theta": "rope_theta",
-           "norm_eps": "rms_norm_eps"}
-# fields the configuration file may set apart from the program's own entry
-_CUT = ("n_layers", "norm_eps")
+from bench import families
 
 
 def arch_config(model: dict, check: bool = True):
-    """The program's ``ArchConfig`` for a configuration file.  With
-    ``check``, every width must equal the program's own entry for the
-    architecture: a drift there would change the model under the cell."""
+    """The program's ``ArchConfig`` for a configuration file, as its family
+    (``bench/families``) reads it.  With ``check``, every width must equal
+    the program's own entry for the architecture."""
     from repro.configs import get_config
+    fam = families.family(model)
     base = get_config(model["program_arch"])
-    kw = {f: type(getattr(base, f))(model[k]) if getattr(base, f) is not None
-          else model[k] for f, k in _FIELDS.items()}
-    if check:
-        off = {f: (getattr(base, f), v) for f, v in kw.items()
-               if f not in _CUT and getattr(base, f) != v}
-        if off:
-            raise ValueError(f"{model['name']}: configuration differs from "
-                             f"the program's {model['program_arch']}: {off}")
-    if base.family != "dense" or base.mla or base.act != "silu" \
-            or base.qkv_bias or base.local_global_period:
-        raise ValueError(f"{model['program_arch']} is not a llama-"
-                         f"architecture dense model")
+    kw = fam.arch_fields(model, base, check)
     cfg = base.replace(dtype=model.get("torch_dtype", base.dtype), **kw)
     sp = model["sparsity"]
     if (cfg.sparsity.n, cfg.sparsity.m) != (sp["n"], sp["m"]):
@@ -54,30 +35,18 @@ def arch_config(model: dict, check: bool = True):
 
 def params_fn(cfg, model: dict):
     """A jitted ``key -> params``: the whole compressed serving tree, made
-    on the device in one executable.  Layers are made one at a time inside
-    it (``lax.map``), so no more than one dense layer is ever resident."""
+    on the device in one executable, laid out by the model's family."""
     from repro.core.layers import convert_to_compressed
     from repro.models import convert_to_compressed as convert_model
     from repro.models import init_model
+    fam = families.family(model)
     sp = cfg.sparsity
-    d = cfg.d_model
 
-    def norm():
-        return {"scale": jnp.ones((d,), jnp.float32)}
-
-    def one(key, index):
-        lin = {n: convert_to_compressed({"w": w}, sp)
-               for n, w in weights.layer(key, model, index).items()}
-        return {"ln1": norm(),
-                "attn": {n: lin[n] for n in ("wq", "wk", "wv", "wo")},
-                "ln2": norm(),
-                "mlp": {n: lin[n] for n in ("wg", "wu", "wd")}}
+    def pack(w):
+        return convert_to_compressed({"w": w}, sp)
 
     def build(key):
-        layers = jax.lax.map(lambda i: one(key, i),
-                             jnp.arange(cfg.n_layers, dtype=jnp.int32))
-        return {"embed": {"emb": weights.embedding(key, model)},
-                "layers": layers, "lnf": norm()}
+        return fam.params(key, model, cfg, pack)
 
     key = jax.random.PRNGKey(0)
     want = jax.eval_shape(lambda k: convert_model(init_model(k, cfg)[0], cfg),

@@ -1,8 +1,8 @@
 """Plain reference of the served model, independent of the program.
 
-A llama-architecture decoder in float32 ``jax.numpy`` at "highest" matmul
-precision: RMSNorm, rotary embedding (rotate-half), grouped-query causal
-attention, SiLU-gated MLP, LM head tied to the embedding.  Every linear is
+The model's family (``bench/families``) gives the arithmetic of a layer
+and of the LM head, in float32 ``jax.numpy`` at "highest" matmul precision
+(``lin``, ``rms``); this module runs it layer by layer.  Every linear is
 pruned 2:4 by magnitude along its input axis (the two largest of each four
 consecutive weights, ties to the lower index), which is the served model's
 arithmetic.  Weights come from ``bench.weights``, regenerated one layer at
@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
+from bench import families, weights
 
 HIGHEST = jax.lax.Precision.HIGHEST
 PAD = 1024          # sequences are padded to a multiple of this length
@@ -70,21 +70,13 @@ def _mat(w) -> jax.Array:
     return w.astype(jnp.float32)
 
 
-def _lin(x, w):
+def lin(x, w):
+    """``x @ w.T`` in float32, for a plain or a quantised ``w``."""
     return jnp.dot(x, _mat(w).T, precision=HIGHEST)
 
 
-def _rms(x, eps):
+def rms(x, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
-
-
-def _rope(x, theta):
-    t, _, hd = x.shape
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv
-    c, s = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
 
 
 class Reference:
@@ -96,6 +88,7 @@ class Reference:
             raise ValueError(f"precision must be f32, int8 or fp8: "
                              f"{precision!r}")
         self.model = model
+        self.family = families.family(model)
         self.key = weights.seed_key(seed)
         self.precision = precision
         sp = model["sparsity"]
@@ -104,7 +97,7 @@ class Reference:
     @functools.partial(jax.jit, static_argnums=0)
     def _layer(self, key, index):
         out = {}
-        for name, w in weights.layer(key, self.model, index).items():
+        for name, w in self.family.layer(key, self.model, index).items():
             w = prune_2_4(w, self.n, self.m)
             out[name] = (QUANTIZE[self.precision](w)
                          if self.precision != "f32" else w)
@@ -117,38 +110,11 @@ class Reference:
 
     @functools.partial(jax.jit, static_argnums=0)
     def _block(self, w, h):
-        md = self.model
-        t = h.shape[0]
-        nh, kv, hd = (md["num_attention_heads"], md["num_key_value_heads"],
-                      md["head_dim"])
-        eps, theta = md["rms_norm_eps"], md["rope_theta"]
-        x = _rms(h, eps)
-        q = _rope(_lin(x, w["wq"]).reshape(t, nh, hd), theta)
-        k = _rope(_lin(x, w["wk"]).reshape(t, kv, hd), theta)
-        v = _lin(x, w["wv"]).reshape(t, kv, hd)
-        g = nh // kv
-        qg = q.reshape(t // Q_CHUNK, Q_CHUNK, kv, g, hd)
-        pos_k = jnp.arange(t)
-
-        def chunk(args):
-            qc, start = args
-            s = jnp.einsum("qkgd,tkd->kgqt", qc, k,
-                           precision=HIGHEST) * hd ** -0.5
-            pos_q = start + jnp.arange(Q_CHUNK)
-            s = jnp.where(pos_k[None, :] <= pos_q[:, None], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum("kgqt,tkd->qkgd", p, v, precision=HIGHEST)
-
-        o = jax.lax.map(chunk, (qg, jnp.arange(0, t, Q_CHUNK)))
-        h = h + _lin(o.reshape(t, nh * hd), w["wo"])
-        x = _rms(h, eps)
-        a = _lin(x, w["wg"])
-        return h + _lin(jax.nn.silu(a) * _lin(x, w["wu"]), w["wd"])
+        return self.family.block(self.model, w, h)
 
     @functools.partial(jax.jit, static_argnums=0)
     def _head(self, emb, h, rows):
-        x = _rms(h[rows], self.model["rms_norm_eps"])
-        return _lin(x, emb)
+        return self.family.head(self.model, emb, h[rows])
 
     def logits(self, seqs: Sequence[np.ndarray],
                rows: Sequence[np.ndarray]) -> List[np.ndarray]:

@@ -2,6 +2,8 @@
 entry names, found by name under ``bench/``.
 
 - ``configs/<config>.json``: the model as run, with its engine geometry;
+- ``families/<family>.py``: the model family the configuration names
+  (``families/__init__.py``);
 - ``traffic/<mix>.json``: the traffic mix (``traffic/__init__.py``);
 - ``cells/<workload>.json``: what belongs to the pair alone: the offered
   rate of an open-loop cell and the limits of its output check;

@@ -5,7 +5,7 @@ with the chat mix scaled down."""
 from bench import spec
 
 MODEL = {
-    "name": "smoke", "program_arch": "deepseek-67b",
+    "name": "smoke", "program_arch": "deepseek-67b", "family": "llama",
     "hidden_size": 256, "intermediate_size": 512,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 64,
     "num_hidden_layers": 2, "vocab_size": 512, "rope_theta": 10000.0,

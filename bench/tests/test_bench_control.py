@@ -103,7 +103,9 @@ def test_reference_prunes_what_the_program_packs():
     from repro.core.sparsity import compress, decompress
 
     from bench import reference
-    w = weights.tensor(weights.seed_key(5), "wd", 0, (64, 256), 0.1)
+    from bench import families
+    wd = families.family(smoke.MODEL).ROLES["wd"]
+    w = weights.tensor(weights.seed_key(5), wd, 0, (64, 256), 0.1)
     w = w.at[0, :4].set(jnp.asarray([1, -1, 1, 0.5], w.dtype))   # a tie
     got = reference.prune_2_4(w)
     want = decompress(compress(w, 2, 4))

@@ -55,6 +55,7 @@ def test_ttft_and_gap_arithmetic(driven):
     gaps = np.concatenate([np.diff(r.tokens) for r in recs])
     assert (gaps >= 0).all()
     assert e2e["itl_p50_ms"] == pytest.approx(1e3 * np.median(gaps))
+    assert e2e["itl_p95_ms"] == pytest.approx(1e3 * np.percentile(gaps, 95))
     assert e2e["itl_p99_ms"] == pytest.approx(1e3 * np.percentile(gaps, 99))
     w0, w1 = log.window
     n = sum(1 for r in log.recs.values() for t in r.tokens if w0 <= t < w1)

@@ -117,7 +117,10 @@ def test_nothing_to_read_gives_nothing():
 @pytest.fixture(scope="module")
 def traced_smoke():
     """A smoke run of the open loop over the engine, the first second of its
-    window traced as ``bench/run.py`` traces it."""
+    window traced as ``bench/run.py`` traces it: every prompt length primed
+    before the loop, and no Python tracer.  Without either, a process with
+    a cold compile cache spends the traced second compiling admissions
+    under the Python tracer, and one or two ticks are left to map by."""
     model = smoke.MODEL
     cfg = program.arch_config(model, check=False)
     params = program.params_fn(cfg, model)(weights.seed_key(SEED))
@@ -125,12 +128,15 @@ def traced_smoke():
     mix = dict(smoke.MIX)
     load = traffic.build(mix, seed=SEED, vocab=model["vocab_size"],
                          warmup_s=mix["warmup_s"], window_s=1.5, rate=8.0)
+    program.prime(eng, [a.prompt_len for a in load.arrivals])
     w0 = mix["warmup_s"]
     tdir = tempfile.mkdtemp(prefix="bench-spans-")
     marks = {}
 
     def start():
-        jax.profiler.start_trace(tdir)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(tdir, profiler_options=opts)
         marks["trace_on"] = time.perf_counter()
 
     def stop():
@@ -153,7 +159,8 @@ def test_mapped_ticks_land_on_their_annotations(traced_smoke):
     run, notes = traced_smoke
     mapped = program_spans.mapped(run, "serve.step")
     steps = [e for e in notes if e.name == "serve.step"]
-    assert len(mapped) >= 3
+    # enough ticks that the median offset stands on many pairs
+    assert len(mapped) >= 10
     for m in mapped:
         near = min(steps, key=lambda e: abs(e.start - m.start))
         assert abs(near.start - m.start) < 5e-4

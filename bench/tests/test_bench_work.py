@@ -1,10 +1,12 @@
-"""Required work, counted from the model's shapes, against hand counts."""
+"""Required work, counted from the model's shapes by the readers and the
+model's family, against hand counts."""
 
 import json
 import os
 
 import pytest
 
+from bench import families
 from bench.spec import HERE
 
 CONFIGS = os.path.join(HERE, "configs")
@@ -54,7 +56,7 @@ def test_nm_spmv_work_mistral_wq_and_whole_tick():
 def test_paged_attention_work():
     pa = _load("paged_attn_roofline")
     model = _model("deepseek-67b-6L")
-    flops, nbytes = pa.work(model, 1000)
+    flops, nbytes = families.family(model).paged_attn_work(model, 1000)
     assert flops == 4 * 1000 * 64 * 128
     assert nbytes == 2 * 1000 * 8 * 128 * 2
     peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
@@ -63,21 +65,22 @@ def test_paged_attention_work():
 
 
 def test_decode_flops_count_live_rows_only():
-    mfu = _load("decode_mfu_pct")
     model = _model("deepseek-67b-6L")
+    flops = families.family(model).decode_flops
     kept = (8192 * 8192 * 2 + 1024 * 8192 * 2 + 22016 * 8192 * 3) // 2
     per_row = 2 * (6 * kept + 102400 * 8192)
-    assert mfu.flops(model, [10]) == per_row + 4 * 64 * 128 * 6 * 10
-    assert mfu.flops(model, []) == 0
+    assert flops(model, [10]) == per_row + 4 * 64 * 128 * 6 * 10
+    assert flops(model, []) == 0
 
 
 def test_kernels_match_the_names_the_trace_prints():
     import re
-    nm, pa = _load("nm_spmv_roofline"), _load("paged_attn_roofline")
+    nm = _load("nm_spmv_roofline")
     # as a v5e trace printed them (names cut by bench.trace.short)
     assert re.search(nm.KERNEL, "%nm_spmv.69 bf16[64,8192]")
     assert not re.search(nm.KERNEL, "%nm_xwt.48 bf16[128,8192]")
     ds, ml = _model("deepseek-67b-6L"), _model("mistral-large-123b-4L")
-    assert re.search(pa.kernel(ds), "%closed_call.13 f32[64,64,1024]")
-    assert re.search(pa.kernel(ml), "%closed_call.7 f32[64,96,1024]")
-    assert not re.search(pa.kernel(ml), "%closed_call.13 f32[64,64,1024]")
+    kernel = families.family(ds).paged_attn_kernel
+    assert re.search(kernel(ds), "%closed_call.13 f32[64,64,1024]")
+    assert re.search(kernel(ml), "%closed_call.7 f32[64,96,1024]")
+    assert not re.search(kernel(ml), "%closed_call.13 f32[64,64,1024]")
